@@ -188,15 +188,16 @@ def bench_kernel_cycle_model():
     a DMA-bound shape (small M) — the regime the auto-planner picks G=8."""
     from repro.core.analytical import PimConfig
     from repro.core import simulator as sim
-    from repro.kernels.ops import HBM_BYTES_PER_S, PEAK_FLOPS, plan_ring_depth
+    from repro.core.schedule import V5E
+    from repro.kernels.ops import plan_ring_depth
 
     def run():
         out = {}
         for M in (8, 128, 512):   # DMA-bound .. balanced .. compute-bound
             K, bn = 256, 256
             tile_bytes = K * bn * 2
-            t_dma = tile_bytes / HBM_BYTES_PER_S
-            t_cmp = 2 * M * K * bn / PEAK_FLOPS
+            t_dma = tile_bytes / V5E.hbm_bytes_per_s
+            t_cmp = 2 * M * K * bn / V5E.peak_flops
             n_tiles = 16
             serial = n_tiles * (t_dma + t_cmp)                 # in-situ (G=1)
             pipelined = t_dma + t_cmp + (n_tiles - 1) * max(t_dma, t_cmp)
@@ -220,8 +221,9 @@ def bench_streamer_modes():
     def run():
         if len(jax.devices()) < 4:
             return "skipped(<4 devices)"
-        from repro.launch.mesh import make_mesh_compat, mesh_context
-        mesh = make_mesh_compat((2, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         L, D, F, B = 4, 32, 64, 8
         key = jax.random.PRNGKey(0)
         ws = {"w1": jax.random.normal(key, (L, D, F)) * 0.05,
@@ -234,7 +236,7 @@ def bench_streamer_modes():
             return c + jnp.tanh(c @ w["w1"]) @ w["w2"]
 
         outs = {}
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             for mode in ("resident", "insitu", "naive_pp", "gpp"):
                 f = jax.jit(lambda x, ws, m=mode: stream_layers(
                     apply_fn, x, ws, L,
@@ -374,7 +376,7 @@ def bench_dense_timing_samples():
     """Measure per-tile t_dma/t_compute on THIS host and mirror the samples
     into BENCH_kernels.json for `core.schedule.TimingCache.from_bench_json`
     — the measured-feedback loop that replaces the planner's analytic
-    PEAK_FLOPS/HBM_BYTES_PER_S constants with reality."""
+    datasheet peaks (`core.schedule.CHIPS`) with reality."""
     import jax
     import jax.numpy as jnp
     from repro.core.schedule import TimingCache, plan_matmul_tiles
@@ -890,6 +892,8 @@ def bench_serving_observability_overhead():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     try:
         bench_fig4_naive_pp_utilization()

@@ -2,10 +2,6 @@
 # Tier-1 verify gate (ROADMAP.md): the full test suite, -x -q, followed by a
 # serving smoke run (paged engine end-to-end through launch/serve).
 #
-# Known version-gated skips (jax < 0.5 lacks jax.sharding.AxisType /
-# jax.set_mesh) show up as SKIPPED with a reason, not failures — see
-# tests/test_distributed.py and tests/test_checkpoint.py.
-#
 # Usage: scripts/verify.sh [extra pytest args]
 #   e.g. scripts/verify.sh -m tier1      # only the tier1-marked fast gate
 set -euo pipefail

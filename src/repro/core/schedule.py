@@ -361,7 +361,7 @@ class TimingSample:
 class TimingCache:
     """Measured per-tile t_dma/t_compute samples feeding `plan_matmul_tiles`.
 
-    The analytic model (PEAK_FLOPS / HBM_BYTES_PER_S) is a datasheet ideal;
+    The analytic model (a `ChipSpec`'s peaks) is a datasheet ideal;
     real kernels see fused-epilogue overheads, DMA contention, and clock
     throttling.  `benchmarks/run.py` records what one tile *actually* costs
     on this host and the planner then sizes the ring against median measured
@@ -448,11 +448,39 @@ def get_default_timing_cache() -> "TimingCache | None":
 # M/K/N tile planner for the streaming matmul kernel (kernels/gpp_matmul.py)
 # ---------------------------------------------------------------------------
 
-VMEM_BUDGET_BYTES = 100 * 1024 * 1024  # target TPU v5e ~128 MiB/core, headroom
+@dataclasses.dataclass(frozen=True)
+class ChipSpec:
+    """One accelerator's published peaks plus the VMEM a kernel may claim.
 
-# TPU v5e hardware model — single source of truth, also used by kernels/ops.py
-PEAK_FLOPS = 197e12
-HBM_BYTES_PER_S = 819e9
+    `vmem_limit_bytes` is passed to every `pallas_call` as its scoped-VMEM
+    limit AND is the budget the tile/ring planners fill, so one number sets
+    both."""
+
+    peak_flops: float           # bf16 FLOP/s
+    hbm_bytes_per_s: float
+    vmem_limit_bytes: int
+
+
+# Keyed by `jax.Device.device_kind`.  v5e: Google Cloud documentation,
+# "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 128 MiB VMEM per core — 100 MiB
+# claimed, the rest left to Mosaic's internal scratch).
+CHIPS = {
+    "TPU v5 lite": ChipSpec(peak_flops=197e12, hbm_bytes_per_s=819e9,
+                            vmem_limit_bytes=100 * 1024 * 1024),
+}
+V5E = CHIPS["TPU v5 lite"]     # analytic default; CPU interpret runs' target
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """The `CHIPS` entry for a device kind; an unknown kind is an error,
+    never a silent v5e default."""
+    spec = CHIPS.get(device_kind)
+    if spec is None:
+        raise ValueError(
+            f"no ChipSpec for device kind {device_kind!r}: add its published "
+            f"peaks to repro.core.schedule.CHIPS (known: {sorted(CHIPS)})")
+    return spec
+
 
 _LANE = 128     # TPU lane width: block_n granularity
 _SUBLANE = 8    # f32 sublane: block_m / block_k granularity
@@ -464,19 +492,20 @@ def round_up(x: int, mult: int) -> int:
 
 
 def _resolve_rates(flops_per_s, transfer_bytes_per_s,
-                   timing: "TimingCache | None") -> "tuple[float, float]":
+                   timing: "TimingCache | None",
+                   chip: ChipSpec) -> "tuple[float, float]":
     """Shared rate resolution for the tile/ring planners: an explicit
     `timing` cache (or, when nothing was passed, the ambient default cache)
-    replaces the analytic datasheet constants with median measured rates;
+    replaces the chip's datasheet peaks with median measured rates;
     explicitly passed rate kwargs win over the ambient default."""
     if timing is None and flops_per_s is None and transfer_bytes_per_s is None:
         timing = _DEFAULT_TIMING
     if timing is not None and len(timing):
         flops_per_s, transfer_bytes_per_s = timing.effective_rates()
     if flops_per_s is None:
-        flops_per_s = PEAK_FLOPS
+        flops_per_s = chip.peak_flops
     if transfer_bytes_per_s is None:
-        transfer_bytes_per_s = HBM_BYTES_PER_S
+        transfer_bytes_per_s = chip.hbm_bytes_per_s
     return flops_per_s, transfer_bytes_per_s
 
 
@@ -528,11 +557,12 @@ def plan_matmul_tiles(
     block_n: int | None = None,
     block_k: int | None = None,
     num_bufs: int | None = None,
-    vmem_budget: int = VMEM_BUDGET_BYTES,
+    vmem_budget: "int | None" = None,
     max_ring: int = 8,
     flops_per_s: "float | None" = None,
     transfer_bytes_per_s: "float | None" = None,
     timing: "TimingCache | None" = None,
+    chip: ChipSpec = V5E,
 ) -> MatmulTilePlan:
     """Pick (block_m, block_n, block_k, num_bufs) under the VMEM budget.
 
@@ -540,7 +570,9 @@ def plan_matmul_tiles(
     and shrink (block_k first, then block_m, then ring depth, then block_n)
     until the working set fits, instead of erroring like the old 1-D kernel.
     Raises only if the *pinned* configuration cannot fit at minimum sizes of
-    every free dim.
+    every free dim.  `vmem_budget` defaults to `chip.vmem_limit_bytes`, the
+    scoped limit the kernel is compiled with.  A free block_k that splits K
+    stays a multiple of the 128-lane tile (it is the lane dim of x's block).
 
     `timing` (or, when omitted, the cache installed via
     `set_default_timing_cache`) replaces the analytic flops_per_s /
@@ -551,7 +583,9 @@ def plan_matmul_tiles(
     not over an explicitly passed `timing`).
     """
     flops_per_s, transfer_bytes_per_s = _resolve_rates(
-        flops_per_s, transfer_bytes_per_s, timing)
+        flops_per_s, transfer_bytes_per_s, timing, chip)
+    if vmem_budget is None:
+        vmem_budget = chip.vmem_limit_bytes
     if M < 1 or K < 1 or N < 1:
         raise ValueError(f"bad matmul shape M={M} K={K} N={N}")
     if num_bufs is not None and num_bufs < 1:
@@ -580,7 +614,7 @@ def plan_matmul_tiles(
     g = ring_for(bm, bk, bn)
     while not fits(bm, bk, bn, g):
         if block_k is None and bk > _LANE:
-            bk = max(_LANE, round_up(bk // 2, _SUBLANE))
+            bk = max(_LANE, round_up(bk // 2, _LANE))
         elif block_m is None and bm > _SUBLANE:
             bm = max(_SUBLANE, round_up(bm // 2, _SUBLANE))
         elif num_bufs is None and g > 1:
@@ -645,12 +679,13 @@ def plan_paged_attn(
     block_bytes: int,
     compute_flops: float,
     fixed_bytes: int = 0,
-    vmem_budget: int = VMEM_BUDGET_BYTES,
+    vmem_budget: "int | None" = None,
     max_ring: int = 8,
     num_bufs: "int | None" = None,
     flops_per_s: "float | None" = None,
     transfer_bytes_per_s: "float | None" = None,
     timing: "TimingCache | None" = None,
+    chip: ChipSpec = V5E,
 ) -> PagedAttnPlan:
     """Pick the KV-block ring depth for the paged-attention kernel.
 
@@ -662,12 +697,15 @@ def plan_paged_attn(
     Rates come from the same measured-feedback path as `plan_matmul_tiles`:
     an explicit/ambient `TimingCache` overrides the analytic constants, with
     compiled-run samples preferred over host ones.  The ring shrinks (never
-    errors) until fixed + G*block_bytes fits the VMEM budget.
+    errors) until fixed + G*block_bytes fits the VMEM budget, which defaults
+    to `chip.vmem_limit_bytes` (the kernel's scoped limit).
     """
     if block_bytes <= 0:
         raise ValueError("block_bytes must be positive")
     flops_per_s, transfer_bytes_per_s = _resolve_rates(
-        flops_per_s, transfer_bytes_per_s, timing)
+        flops_per_s, transfer_bytes_per_s, timing, chip)
+    if vmem_budget is None:
+        vmem_budget = chip.vmem_limit_bytes
     if num_bufs is not None:
         if num_bufs < 1:
             raise ValueError("num_bufs >= 1")

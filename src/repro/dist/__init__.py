@@ -1,7 +1,6 @@
 """Distributed policy package: sharding rules for the launch-time steps.
 
 `repro.dist.sharding` maps param/cache pytrees to PartitionSpecs under a
-small rule object (`ShardRules`).  The planned fault-tolerance module
-(`repro.dist.fault`) is still unbuilt — `repro.launch.train` falls back to
-its inline StepWatchdog when the import fails.
+small rule object (`ShardRules`).  The training loop's straggler watchdog
+lives with the loop (`repro.launch.train.StepWatchdog`).
 """

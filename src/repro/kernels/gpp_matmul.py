@@ -52,10 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.schedule import plan_matmul_tiles
-
-# renamed CompilerParams -> TPUCompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro.core.schedule import V5E, ChipSpec, chip_spec, plan_matmul_tiles
 
 _ACTIVATIONS = {
     None: lambda x: x,
@@ -68,10 +65,36 @@ _ACTIVATIONS = {
 }
 
 
-def _chunk_bounds(K: int, chunks: int, c: int) -> tuple[int, int]:
-    base = K // chunks
-    lo = c * base
-    hi = K if c == chunks - 1 else lo + base
+def target_chip(interpret: bool) -> ChipSpec:
+    """The chip a kernel call is planned and compiled for: the v5e entry for
+    CPU interpret runs, else the attached device's own `CHIPS` entry (an
+    unknown kind raises)."""
+    return V5E if interpret else chip_spec(jax.devices()[0].device_kind)
+
+
+def sublane_tile(itemsize: int) -> int:
+    """Rows of one native (sublane, 128-lane) VMEM tile: 8 for 32-bit, 16
+    for bf16, 32 for int8 — the granularity a DMA may slice rows at."""
+    return 8 * max(1, 4 // itemsize)
+
+
+def ring_chunks(G: int, rows: int, tile: int) -> int:
+    """Chunks C per ring slot: G-1 (the GPP schedule), capped so every chunk
+    is a non-empty whole number of `tile`-row tiles.  A block whose rows are
+    not a tile multiple moves whole (C = 1)."""
+    if G <= 1 or rows % tile:
+        return 1
+    return max(1, min(G - 1, rows // tile))
+
+
+def _chunk_bounds(rows: int, chunks: int, c: int,
+                  tile: int = 1) -> tuple[int, int]:
+    """Rows [lo, hi) of chunk c: the rows//tile whole tiles split as evenly
+    as possible (sizes differ by at most one tile), the last chunk taking
+    any remainder."""
+    units = rows // tile
+    lo = (c * units // chunks) * tile
+    hi = rows if c == chunks - 1 else ((c + 1) * units // chunks) * tile
     return lo, hi
 
 
@@ -175,28 +198,35 @@ def matmul_lane_events(trace, M: int, K: int, N: int, *,
                        itemsize: int = 4, t0_us: float, dur_us: float,
                        pid: int = 0, max_events: int = 256) -> int:
     """Schedule-exact DMA/compute lanes for one `gpp_matmul(M,K,N)` call:
-    plans the same tiles/ring the kernel wrapper would and replays the
-    chunk schedule into `trace` over the measured window."""
-    plan = plan_matmul_tiles(M, K, N, x_itemsize=itemsize,
-                             w_itemsize=itemsize, out_itemsize=itemsize)
+    plans the same tiles/ring the kernel wrapper would (for the attached
+    TPU, or the v5e entry off-TPU) and replays the chunk schedule into
+    `trace` over the measured window."""
+    plan = plan_matmul_tiles(
+        M, K, N, x_itemsize=itemsize, w_itemsize=itemsize,
+        out_itemsize=itemsize,
+        chip=target_chip(interpret=jax.default_backend() != "tpu"))
     num_m, num_n, num_k = plan.grid(M, N, K)
     steps = num_m * num_n * num_k
     G = min(plan.num_bufs, max(1, steps))
-    C = max(1, min(G - 1, plan.block_k))
+    C = ring_chunks(G, plan.block_k, sublane_tile(itemsize))
     return schedule_lane_events(
         trace, num_steps=steps, G=G, C=C, t0_us=t0_us, dur_us=dur_us,
         step_bytes=plan.block_k * plan.block_n * itemsize,
         pid=pid, max_events=max_events, name="matmul")
 
 
-def _make_chunk_ops(w_hbm, ring, sems, G: int, C: int, bk: int, tile_slice):
+def _make_chunk_ops(ring, sems, G: int, C: int, bk: int, tile_slice):
     """(start_chunk, wait_chunk) pair for the ring's chunk DMAs, shared by
     the flat and grouped kernels.  `tile_slice(step, lo, hi)` returns the
-    w_hbm sub-ref holding rows [lo, hi) of the weight tile consumed at
-    `step` — the step->tile mapping is the only per-grid difference."""
+    HBM sub-ref holding rows [lo, hi) of the weight tile consumed at
+    `step` — the step->tile mapping is the only per-grid difference.  Chunk
+    bounds are whole sublane tiles of the ring's dtype (Mosaic refuses a
+    DMA slice that is not tile-aligned)."""
+    tile = sublane_tile(ring.dtype.itemsize)
+
     def _copy(step, c: int):
         slot = jax.lax.rem(step, G)
-        lo, hi = _chunk_bounds(bk, C, c)
+        lo, hi = _chunk_bounds(bk, C, c, tile)
         return pltpu.make_async_copy(
             tile_slice(step, lo, hi),
             ring.at[slot, pl.ds(lo, hi - lo), :],
@@ -272,7 +302,7 @@ def _gpp_kernel(*refs, grid_mnk: tuple, num_bufs: int, bm: int, bn: int,
         n_idx, k_idx = t // nk, jax.lax.rem(t, nk)
         return w_hbm.at[pl.ds(k_idx * bk + lo, hi - lo), pl.ds(n_idx * bn, bn)]
 
-    start_chunk, wait_chunk = _make_chunk_ops(w_hbm, ring, sems, G, C, bk,
+    start_chunk, wait_chunk = _make_chunk_ops(ring, sems, G, C, bk,
                                               tile_slice)
 
     _run_chunk_schedule(s, S, G, C, start_chunk, wait_chunk)
@@ -347,7 +377,9 @@ def gpp_matmul(
          are zero-padded, not errors.
       num_bufs: ring depth G — 1: in-situ, 2: naive ping-pong, >=3: GPP.
          None: planned from the DMA:compute ratio of one tile.
-      vmem_budget: on-chip working-set budget in bytes (default ~100 MiB).
+      vmem_budget: scoped-VMEM limit in bytes the kernel is compiled with and
+         the tiles are planned against (default: the target chip's
+         `vmem_limit_bytes`, 100 MiB on v5e).
       interpret: run the kernel body in interpret mode (CPU validation).
     """
     M, K = x.shape
@@ -360,21 +392,22 @@ def gpp_matmul(
         raise ValueError(f"unknown activation {activation!r}")
     out_dtype = x.dtype
 
-    plan_kw = dict(vmem_budget=vmem_budget) if vmem_budget is not None else {}
+    chip = target_chip(interpret)
+    vmem_limit = vmem_budget or chip.vmem_limit_bytes
     plan = plan_matmul_tiles(
         M, K, N,
         x_itemsize=x.dtype.itemsize,
         w_itemsize=w.dtype.itemsize,
         out_itemsize=jnp.dtype(out_dtype).itemsize,
         block_m=block_m, block_n=block_n, block_k=block_k,
-        num_bufs=num_bufs, **plan_kw,
+        num_bufs=num_bufs, vmem_budget=vmem_limit, chip=chip,
     )
     bm, bn, bk = plan.block_m, plan.block_n, plan.block_k
     num_m, num_n, num_k = plan.grid(M, N, K)
     G = min(plan.num_bufs, max(1, num_m * num_n * num_k))
-    # chunks per tile: C = G-1 splits of the block_k sublanes (clamped so
-    # every chunk is non-empty even for tiny K tiles).
-    C = max(1, min(G - 1, bk))
+    # chunks per tile: C = G-1 splits of the block_k sublanes, capped to
+    # whole sublane tiles of w's dtype
+    C = ring_chunks(G, bk, sublane_tile(w.dtype.itemsize))
 
     # zero-pad ragged edges to full tiles (K-padding is correctness-neutral;
     # M/N padding is sliced off the output).
@@ -416,8 +449,9 @@ def gpp_matmul(
             pltpu.VMEM((G, bk, bn), w.dtype),                # weight ring
             pltpu.SemaphoreType.DMA((G,)),                   # per-slot DMA sems
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3,          # sequential grid
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
     )(*operands)
@@ -474,7 +508,7 @@ def _gpp_grouped_kernel(*refs, grid_emnk: tuple, num_bufs: int, bm: int,
         return w_hbm.at[e_idx, pl.ds(k_idx * bk + lo, hi - lo),
                         pl.ds(n_idx * bn, bn)]
 
-    start_chunk, wait_chunk = _make_chunk_ops(w_hbm, ring, sems, G, C, bk,
+    start_chunk, wait_chunk = _make_chunk_ops(ring, sems, G, C, bk,
                                               tile_slice)
 
     # same chunk schedule as `_gpp_kernel`, over E*SM global steps: the
@@ -558,19 +592,20 @@ def gpp_matmul_grouped(
         raise ValueError(f"unknown activation {activation!r}")
     out_dtype = x.dtype
 
-    plan_kw = dict(vmem_budget=vmem_budget) if vmem_budget is not None else {}
+    chip = target_chip(interpret)
+    vmem_limit = vmem_budget or chip.vmem_limit_bytes
     plan = plan_matmul_tiles(
         M, K, N,
         x_itemsize=x.dtype.itemsize,
         w_itemsize=w.dtype.itemsize,
         out_itemsize=jnp.dtype(out_dtype).itemsize,
         block_m=block_m, block_n=block_n, block_k=block_k,
-        num_bufs=num_bufs, **plan_kw,
+        num_bufs=num_bufs, vmem_budget=vmem_limit, chip=chip,
     )
     bm, bn, bk = plan.block_m, plan.block_n, plan.block_k
     num_m, num_n, num_k = plan.grid(M, N, K)
     G = min(plan.num_bufs, max(1, E * num_m * num_n * num_k))
-    C = max(1, min(G - 1, bk))
+    C = ring_chunks(G, bk, sublane_tile(w.dtype.itemsize))
 
     Mp, Kp, Np = num_m * bm, num_k * bk, num_n * bn
     xp = _pad3(x, Mp, Kp)
@@ -614,8 +649,9 @@ def gpp_matmul_grouped(
             pltpu.VMEM((G, bk, bn), w.dtype),                # weight ring
             pltpu.SemaphoreType.DMA((G,)),                   # per-slot sems
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 4,          # sequential grid
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
     )(*operands)

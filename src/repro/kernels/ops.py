@@ -2,10 +2,11 @@
 
 `streamed_matmul` picks the ring depth from the same GPP planner that the
 paper's analytic model validates (`repro.core.schedule.plan_stream`), using
-TPU v5e constants: a (block_k, bn) bf16 tile moves block_k*bn*2 bytes at
-~819 GB/s HBM while the MXU computes 2*M*block_k*bn flops at ~197 TFLOP/s,
-so t_dma/t_compute ≈ 120/M for bf16 — small M (the paper's small-n_in
-regime) is exactly where deep rings win.
+the target chip's peaks (`core.schedule.CHIPS`).  On a TPU v5e a
+(block_k, bn) bf16 tile moves block_k*bn*2 bytes at ~819 GB/s HBM while the
+MXU computes 2*M*block_k*bn flops at ~197 TFLOP/s, so t_dma/t_compute ≈
+120/M for bf16 — small M (the paper's small-n_in regime) is exactly where
+deep rings win.
 
 `dense` is the single model-facing matmul entry point for the whole model
 zoo.  Routing table (who calls it, with what weight layout):
@@ -46,8 +47,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.schedule import HBM_BYTES_PER_S, PEAK_FLOPS, plan_stream
-from repro.kernels.gpp_matmul import _ACTIVATIONS, gpp_matmul, gpp_matmul_grouped
+from repro.core.schedule import V5E, ChipSpec, plan_stream
+from repro.kernels.gpp_matmul import (_ACTIVATIONS, gpp_matmul,
+                                      gpp_matmul_grouped, target_chip)
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ref import dense_grouped_ref, dense_ref, paged_attn_ref
 
@@ -65,14 +67,15 @@ DENSE_MODES = ("auto", "ref", "kernel", "interpret")
 PAGED_ATTN_MODES = ("auto", "ref", "pallas", "interpret")
 
 
-def plan_ring_depth(M: int, K: int, block_n: int, dtype=jnp.bfloat16, max_ring: int = 8) -> int:
+def plan_ring_depth(M: int, K: int, block_n: int, dtype=jnp.bfloat16,
+                    max_ring: int = 8, chip: ChipSpec = V5E) -> int:
     """Ring depth G = ceil(t_dma / t_compute) + 1 for one weight tile."""
     itemsize = jnp.dtype(dtype).itemsize
     plan = plan_stream(
         block_bytes=K * block_n * itemsize,
         compute_flops=2.0 * M * K * block_n,
-        flops_per_s=PEAK_FLOPS,
-        transfer_bytes_per_s=HBM_BYTES_PER_S,
+        flops_per_s=chip.peak_flops,
+        transfer_bytes_per_s=chip.hbm_bytes_per_s,
         max_ring=max_ring,
     )
     return plan.ring_depth
@@ -117,7 +120,8 @@ def streamed_gemm_sequence(
     R, K, N = ws.shape
     w_flat = jnp.transpose(ws, (1, 0, 2)).reshape(K, R * N)
     if num_bufs is None:
-        num_bufs = plan_ring_depth(x.shape[0], K, block_n, x.dtype)
+        num_bufs = plan_ring_depth(x.shape[0], K, block_n, x.dtype,
+                                   chip=target_chip(interpret))
     y = gpp_matmul(x, w_flat, block_n=block_n, num_bufs=num_bufs, interpret=interpret)
     M = x.shape[0]
     return jnp.transpose(y.reshape(M, R, N), (1, 0, 2))
@@ -130,39 +134,34 @@ def _ambient_mesh_active() -> bool:
     (the exact traffic blowup the streaming path exists to avoid).  Callers
     that hold per-rank local arrays (inside shard_map) can still opt in with
     an explicit mode="kernel"."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        return mesh is not None and not mesh.empty
-    except Exception:  # noqa: BLE001 — older jax: no ambient-mesh API
-        return False
+    return not jax.sharding.get_abstract_mesh().empty
 
 
 def _targets_tpu(*arrays) -> bool:
-    """Best-effort check that the computation will land on TPU: committed
-    concrete arrays reveal their devices (every inspectable array must be on
-    TPU); tracers (under jit) don't, so we fall back to the process default
-    backend.  Work explicitly pinned to CPU inside a jit on a TPU host can
-    still mis-route — pass mode="ref" there."""
-    saw_devices = False
-    for a in arrays:
-        devices = getattr(a, "devices", None)
-        if callable(devices):
-            try:
-                if not all(d.platform == "tpu" for d in devices()):
-                    return False
-                saw_devices = True
-            except Exception:
-                continue
-    return saw_devices or jax.default_backend() == "tpu"
+    """Whether the computation will land on TPU: concrete arrays reveal
+    their devices (every one must be on TPU); tracers (under jit) don't, so
+    the process default backend decides.  Work explicitly pinned to CPU
+    inside a jit on a TPU host can still mis-route — pass mode="ref"
+    there."""
+    concrete = [a for a in arrays if isinstance(a, jax.Array)
+                and not isinstance(a, jax.core.Tracer)]
+    if concrete:
+        return all(d.platform == "tpu" for a in concrete for d in a.devices())
+    return jax.default_backend() == "tpu"
 
 
-def _resolve_auto_mode(x, w) -> str:
-    """The single auto-routing policy for `dense` and `dense_grouped`:
-    kernel on TPU when w is in the streaming regime AND no ambient SPMD
-    mesh would have to all-gather it into the (unpartitionable) pallas_call;
-    else the bit-identical ref path."""
+def resolve_dense_mode(mode: str, w, *arrays) -> str:
+    """The single routing policy for `dense` and `dense_grouped`: validates
+    `mode` and resolves "auto" to kernel on TPU when w is in the streaming
+    regime AND no ambient SPMD mesh would have to all-gather it into the
+    (unpartitionable) pallas_call; else the bit-identical ref path.
+    `arrays` are the call's other operands (their placement counts too)."""
+    if mode not in DENSE_MODES:
+        raise ValueError(f"dense mode must be one of {DENSE_MODES}, got {mode!r}")
+    if mode != "auto":
+        return mode
     w_bytes = w.size * w.dtype.itemsize
-    return ("kernel" if _targets_tpu(x, w)
+    return ("kernel" if _targets_tpu(w, *arrays)
             and w_bytes >= DENSE_KERNEL_MIN_BYTES
             and not _ambient_mesh_active() else "ref")
 
@@ -230,8 +229,7 @@ def dense(
       interpret  the Pallas kernel in interpret mode (CPU validation)
       ref        fused jnp fallback (identical math to the pre-kernel models)
     """
-    if mode not in DENSE_MODES:
-        raise ValueError(f"dense mode must be one of {DENSE_MODES}, got {mode!r}")
+    mode = resolve_dense_mode(mode, w, x)
     if activation not in _ACTIVATIONS:
         raise ValueError(f"unknown activation {activation!r}")
     if not 1 <= contract_dims <= min(x.ndim, w.ndim):
@@ -250,8 +248,6 @@ def dense(
     w2 = w.reshape(Kf, Nf)
     if bias is not None:
         bias = bias.reshape(Nf)
-    if mode == "auto":
-        mode = _resolve_auto_mode(x, w)
     if mode == "ref":
         if w_scale is not None:
             w2 = (w2.astype(jnp.float32)
@@ -303,6 +299,9 @@ def paged_attn(
     GQA: pools are k/v, dk = head_dim.  MLA (`mla=True`): pools are
     c_kv/k_rope, q is already absorbed through w_uk (dk = kv_lora + rope),
     and the return value is the latent output for the caller to up-project.
+    A k_rope pool wider than q's rope lanes (zero-padded to whole 128-lane
+    tiles, `models.attention.paged_cache_specs`) gets q zero-padded to
+    match, so the padding adds nothing to the logits.
 
     mode:
       auto       pallas on TPU, else ref
@@ -312,6 +311,10 @@ def paged_attn(
                  pre-kernel serving read path, bit-for-bit
     """
     mode = resolve_paged_attn_mode(mode, q, pool_a, pool_b)
+    if mla:
+        pad = pool_a.shape[-1] + pool_b.shape[-1] - q.shape[-1]
+        if pad:
+            q = jnp.pad(q, [(0, 0)] * 3 + [(0, pad)])
     if mode == "ref":
         return paged_attn_ref(q, pool_a, pool_b, tables, positions,
                               num_kv_heads=num_kv_heads, scale=scale,
@@ -374,8 +377,7 @@ def dense_grouped(
     batched-einsum math bit-for-bit (dequant pre-scales the weights, like
     `dense`'s ref path).
     """
-    if mode not in DENSE_MODES:
-        raise ValueError(f"dense mode must be one of {DENSE_MODES}, got {mode!r}")
+    mode = resolve_dense_mode(mode, w, x)
     if activation not in _ACTIVATIONS:
         raise ValueError(f"unknown activation {activation!r}")
     if x.ndim != 3 or w.ndim != 3:
@@ -383,8 +385,6 @@ def dense_grouped(
                          f"got x{x.shape} w{w.shape}")
     if x.shape[0] != w.shape[0] or x.shape[2] != w.shape[1]:
         raise ValueError(f"grouped shape mismatch: x{x.shape} @ w{w.shape}")
-    if mode == "auto":
-        mode = _resolve_auto_mode(x, w)
     if mode == "ref":
         if w_scale is not None:
             sc = jnp.asarray(w_scale, jnp.float32)

@@ -47,12 +47,14 @@ One kernel body serves the whole family:
       exact same math as the gather path, reassociated.
 
 Queries arrive pre-scaled and pre-transposed as (B, KVH, rep*S, dk) so the
-kernel body is nothing but DMA waits, two batched dot_generals, and the
-online-softmax update — no in-kernel transposes.  Decode is S=1 with
-per-lane positions; a prefill chunk is B=1, S=chunk with ANY start position
-(prefix-cache hits resume prefill mid-block — the visibility predicate and
-per-(row, slot) mask are position-exact, never block-aligned-assuming);
-both compile to the same kernel.
+kernel body is nothing but DMA waits, two batched dot_generals (batch =
+KV head, leading), and the online-softmax update — no in-kernel transposes
+or reshapes: a (bs, KVH*hd) KV tile splits into heads by static lane
+slices.  Decode is S=1 with per-lane positions; a prefill chunk is B=1,
+S=chunk with ANY start position (prefix-cache hits resume prefill
+mid-block — the visibility predicate and per-(row, slot) mask are
+position-exact, never block-aligned-assuming); both compile to the same
+kernel.
 
 Shared-prefix aliasing contract: with the prefix cache on, one physical
 block may appear in SEVERAL lanes' tables (refcounted shares of a common
@@ -74,17 +76,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import plan_paged_attn
-from repro.kernels.gpp_matmul import (_CompilerParams, _make_chunk_ops,
-                                      _run_chunk_schedule,
-                                      schedule_lane_events)
+from repro.kernels.gpp_matmul import (_make_chunk_ops, _run_chunk_schedule,
+                                      ring_chunks, schedule_lane_events,
+                                      sublane_tile, target_chip)
 
 NEG_INF = float("-inf")
 
 
 def paged_lane_events(trace, live_counts: "list[int]", max_blocks: int, *,
-                      G: int = 4, block_bytes: int = 0, t0_us: float,
-                      dur_us: float, pid: int = 0,
-                      max_events: int = 128) -> int:
+                      block_rows: int, itemsize: int, G: int = 4,
+                      block_bytes: int = 0, t0_us: float, dur_us: float,
+                      pid: int = 0, max_events: int = 128) -> int:
     """DMA/compute trace lanes for one paged-attention call.
 
     Replays the kernel's lane-major (B, MB) grid — `live_counts[lane]` live
@@ -94,18 +96,43 @@ def paged_lane_events(trace, live_counts: "list[int]", max_blocks: int, *,
     over the measured call window.  Dead steps (blocks past a lane's
     position) cost the kernel neither DMA nor compute, so they get zero
     width on the modeled timebase; see
-    `kernels.gpp_matmul.schedule_lane_events`."""
+    `kernels.gpp_matmul.schedule_lane_events`.  `block_rows` (tokens per
+    KV block) and `itemsize` (pool dtype) set the chunk count exactly as the
+    kernel does."""
     B = len(live_counts)
     steps = B * max_blocks
     if steps <= 0:
         return 0
     G = min(G, steps)
-    C = max(1, G - 1)
+    C = ring_chunks(G, block_rows, sublane_tile(itemsize))
     return schedule_lane_events(
         trace, num_steps=steps,
         G=G, C=C, t0_us=t0_us, dur_us=dur_us, step_bytes=block_bytes,
         live=lambda s: (s % max_blocks) < live_counts[s // max_blocks],
         pid=pid, max_events=max_events, name="kv")
+
+
+def _split_heads(tile, kvh: int):
+    """(bs, KVH*hd) -> (KVH, bs, hd) by static lane slices stacked on a new
+    leading axis: Mosaic has no lane-splitting reshape, and the batched
+    dot_generals want their batch (head) dimension leading."""
+    hd = tile.shape[-1] // kvh
+    return jnp.stack([tile[:, h * hd:(h + 1) * hd] for h in range(kvh)])
+
+
+def _dot_nt(a, b):
+    """a @ b^T: (r, d) x (t, d) -> (r, t), accumulated in f32."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _bdot_nt(a, b):
+    """Batched a @ b^T over a leading batch dim: (N, r, d) x (N, t, d) ->
+    (N, r, t), accumulated in f32."""
+    return jax.lax.dot_general(
+        a, b, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
 
 
 def _paged_attn_kernel(tables_ref, pos_ref, q_ref, pa_hbm, pb_hbm, out_ref,
@@ -140,9 +167,9 @@ def _paged_attn_kernel(tables_ref, pos_ref, q_ref, pa_hbm, pb_hbm, out_ref,
             return pool.at[phys, pl.ds(lo, hi - lo), :]
         return slice_fn
 
-    start_a, wait_a = _make_chunk_ops(pa_hbm, ring_a, sem_a, G, C, bs,
+    start_a, wait_a = _make_chunk_ops(ring_a, sem_a, G, C, bs,
                                       tile_slice(pa_hbm))
-    start_b, wait_b = _make_chunk_ops(pb_hbm, ring_b, sem_b, G, C, bs,
+    start_b, wait_b = _make_chunk_ops(ring_b, sem_b, G, C, bs,
                                       tile_slice(pb_hbm))
 
     def start_chunk(step, c):
@@ -170,28 +197,27 @@ def _paged_attn_kernel(tables_ref, pos_ref, q_ref, pa_hbm, pb_hbm, out_ref,
         slot = jax.lax.rem(s, G)
         ka = ring_a[slot]                # (bs, Fa)
         kb = ring_b[slot]                # (bs, Fb)
+        rS = rep * S
         if mla:
-            # weight-absorbed MLA is MQA: one shared key = concat(latent,
-            # rope), values are the latent — no second value ring needed.
-            k3 = jnp.concatenate([ka, kb], axis=-1)[:, None, :]  # (bs, 1, dk)
-            v3 = ka[:, None, :]                                  # (bs, 1, dv)
+            # weight-absorbed MLA is MQA: the key is concat(latent, rope)
+            # along lanes, so its logits are the sum of the two lane blocks'
+            # — no in-kernel concatenate; values are the latent (no second
+            # value ring needed).
+            fa = ka.shape[-1]
+            q2 = q_ref[0, 0]                                 # (rep*S, dk)
+            logits = (_dot_nt(q2[:, :fa], ka)
+                      + _dot_nt(q2[:, fa:], kb)).reshape(1, rS, bs)
+            v3 = ka.reshape(1, bs, fa)                       # (1, bs, dv)
         else:
-            k3 = ka.reshape(bs, kvh, -1)
-            v3 = kb.reshape(bs, kvh, -1)
-
-        # (KVH, rep*S, bs) logits for this block, f32 accumulation.
-        qr = q_ref[0]                    # (KVH, rep*S, dk), pre-scaled
-        logits = jax.lax.dot_general(
-            qr, k3,
-            dimension_numbers=(((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
+            k3 = _split_heads(ka, kvh)   # (KVH, bs, hd)
+            v3 = _split_heads(kb, kvh)
+            logits = _bdot_nt(q_ref[0], k3)  # (KVH, rep*S, bs), f32;
+            #                                  q pre-scaled (KVH, rep*S, dk)
 
         # mask per (query-row, key-slot): flattened row i is (r, s_) with
         # s_ = i % S, so qpos = pos[lane] + s_; key slot t holds absolute
         # position j*bs + t.  Ragged tails, null-block reads, and window
         # expiry all fall out of this one predicate.
-        rS = rep * S
         srow = jax.lax.broadcasted_iota(jnp.int32, (rS, bs), 0) % S
         qpos = pos_ref[b] + srow
         kpos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rS, bs), 1)
@@ -206,9 +232,9 @@ def _paged_attn_kernel(tables_ref, pos_ref, q_ref, pa_hbm, pb_hbm, out_ref,
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         p = jnp.exp(logits - m_safe[..., None])
         corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        pv = jax.lax.dot_general(
+        pv = jax.lax.dot_general(           # (KVH, rep*S, dv)
             p.astype(v3.dtype), v3,
-            dimension_numbers=(((2,), (0,)), ((0,), (1,))),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         m_ref[...] = m_new
@@ -256,6 +282,8 @@ def paged_attention(
       window: sliding-window size; expiry is masked per block.
       num_bufs: KV-block ring depth G; None plans it via
          `core.schedule.plan_paged_attn` (VMEM budget + TimingCache rates).
+      vmem_budget: scoped-VMEM limit the kernel is compiled with and the
+         ring is planned against (default: the target chip's limit).
       interpret: run in interpret mode (CPU validation).
 
     Returns: (B, S, H, dv) attention output in q.dtype (dv = hd, or kv_lora
@@ -304,16 +332,18 @@ def paged_attention(
     itemsize = jnp.dtype(kdtype).itemsize
     fixed = (kvh * rS * (dk + dv) * itemsize      # queries + output block
              + kvh * rS * (dv + 2) * 4)           # f32 acc + m + l
-    plan_kw = dict(vmem_budget=vmem_budget) if vmem_budget is not None else {}
+    chip = target_chip(interpret)
+    vmem_limit = vmem_budget or chip.vmem_limit_bytes
     plan = plan_paged_attn(
         block_bytes=bs * (Fa + Fb) * itemsize,
         compute_flops=2.0 * rS * bs * (dk + dv) * kvh,
         fixed_bytes=fixed,
         num_bufs=num_bufs,
-        **plan_kw,
+        vmem_budget=vmem_limit,
+        chip=chip,
     )
     G = min(plan.num_bufs, max(1, B * MB))
-    C = max(1, min(G - 1, bs))
+    C = ring_chunks(G, bs, sublane_tile(itemsize))
 
     kernel = functools.partial(
         _paged_attn_kernel, grid_bj=(B, MB), S=S, kvh=kvh, rep=rep, bs=bs,
@@ -342,8 +372,9 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, rS, dv), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 2,  # sequential grid
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
     )(tables.astype(jnp.int32), positions.astype(jnp.int32), q2, pa, pb)

@@ -28,7 +28,7 @@ import traceback
 import jax
 
 from repro.configs.base import SHAPES, cells_for
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import make_step
 from repro.models import registry
 
@@ -107,7 +107,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
     }
     t0 = time.time()
     try:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             bundle = make_step(cfg, mesh, shape)
             lowered = bundle.fn.lower(*bundle.input_specs)
             t_lower = time.time() - t0

@@ -1,37 +1,13 @@
-"""Production mesh builders + jax-version compat shims.
+"""Production mesh builders.
 
-A FUNCTION (not a module-level constant) so importing this module never
-touches jax device state — required by the dry-run contract.
-
-The explicit-mesh API (`jax.sharding.AxisType`, `jax.set_mesh`) landed after
-jax 0.4.x; `make_mesh_compat` / `mesh_context` paper over the difference so
-the launchers and the distributed tests run on both: on 0.4.x the mesh is
-built without axis types (Auto is the 0.4.x default semantics anyway) and
-the ambient-mesh context is the `Mesh` context manager itself — explicit
-`NamedSharding`s carry the mesh, so nothing downstream depends on the
-ambient registry being the new one.
+FUNCTIONS (not module-level constants) so importing this module never
+touches jax device state — required by the dry-run contract.  Meshes carry
+Auto axis types (GSPMD propagation); callers make one ambient with
+`jax.set_mesh`.
 """
 from __future__ import annotations
 
 import jax
-
-JAX_HAS_EXPLICIT_MESH = (hasattr(jax.sharding, "AxisType")
-                         and hasattr(jax, "set_mesh"))
-
-
-def make_mesh_compat(shape: "tuple[int, ...]", axes: "tuple[str, ...]"):
-    """jax.make_mesh with Auto axis types where the API exists, plain
-    jax.make_mesh on 0.4.x (same Auto/GSPMD semantics)."""
-    if JAX_HAS_EXPLICIT_MESH:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context: `jax.set_mesh` when available, otherwise the
-    Mesh object itself (a context manager on 0.4.x)."""
-    return jax.set_mesh(mesh) if JAX_HAS_EXPLICIT_MESH else mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,7 +15,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) ("pod", "data", "model") = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2):
@@ -50,7 +27,8 @@ def make_host_mesh(data: int = 2, model: int = 2):
             f"need {n} devices; set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
             "before importing jax"
         )
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

@@ -92,11 +92,13 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.models import registry
     from repro.models import transformer as tf
     from repro.serving import (DenseServingEngine, ServeConfig, ServingEngine,
                                make_engine)
 
+    use_compile_cache()
     cfg = registry.get_config(args.arch, smoke=args.smoke)
     if cfg.input_mode != "tokens":
         raise SystemExit(f"{args.arch} takes embedding inputs; serve the token "

@@ -13,9 +13,30 @@ committed step, straggler watchdog on step wall-times.
 from __future__ import annotations
 
 import argparse
+import collections
 import os
-import sys
+import statistics
 import time
+
+
+class StepWatchdog:
+    """Straggler detector: `record(dt)` is True when a step's wall time
+    exceeds `factor` x the median of recent steps (a bounded window, so the
+    hot loop stays O(window) however long the run)."""
+
+    def __init__(self, factor: float = 3.0, window: int = 256):
+        self.factor = factor
+        self._times = collections.deque(maxlen=window)
+
+    @property
+    def median(self) -> float:
+        return statistics.median(self._times) if self._times else 0.0
+
+    def record(self, dt: float) -> bool:
+        straggled = (len(self._times) >= 3
+                     and dt > self.factor * self.median)
+        self._times.append(dt)
+        return straggled
 
 
 def main(argv=None):
@@ -50,38 +71,15 @@ def main(argv=None):
     from repro.configs.base import ShapeConfig
     from repro.core.streamer import StreamSettings
     from repro.data.pipeline import DataConfig, TokenPipeline
-    try:
-        from repro.dist.fault import StepWatchdog
-    except ImportError:
-        # repro.dist was never built (planned fault-tolerance package).
-        # Inline fallback with the same contract: record(dt) -> True when a
-        # step straggles past 3x the median of recent steps (bounded window
-        # so the hot loop stays O(window) regardless of run length).
-        import collections
-        import statistics
-
-        class StepWatchdog:
-            def __init__(self, factor: float = 3.0, window: int = 256):
-                self.factor = factor
-                self._times = collections.deque(maxlen=window)
-
-            @property
-            def median(self) -> float:
-                return statistics.median(self._times) if self._times else 0.0
-
-            def record(self, dt: float) -> bool:
-                straggled = (len(self._times) >= 3
-                             and dt > self.factor * self.median)
-                self._times.append(dt)
-                return straggled
-    from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                                       mesh_context)
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import make_host_mesh, make_production_mesh
     from repro.launch.steps import make_train_step
     from repro.models import registry
     from repro.models import transformer as tf
     from repro.optim import adafactor as adaf
     from repro.optim import adamw as adam
 
+    use_compile_cache()
     cfg = registry.get_config(args.arch, smoke=args.smoke)
     if args.stream_mode:
         cfg = cfg.with_(stream=StreamSettings(mode=args.stream_mode,
@@ -99,7 +97,7 @@ def main(argv=None):
     print(f"mesh: {dict(mesh.shape)}  devices: {len(jax.devices())}")
 
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_train_step(cfg, mesh, shape)
 
         key = jax.random.PRNGKey(0)

@@ -17,6 +17,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.core.schedule import round_up
 from repro.kernels.ops import dense, paged_attn, resolve_paged_attn_mode
 from repro.models.layers import sds, rope
 
@@ -149,29 +150,19 @@ def causal_mask(S: int, T: int, q_offset: int = 0, window: int | None = None):
     return m
 
 
-def _ambient_constraint(x, spec):
+def ambient_constraint(x, spec):
     """with_sharding_constraint against the ambient mesh, if one is set and
-    covers the named axes (no-op on mesh-less CPU test runs)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        names = set(mesh.axis_names)
-        if not all(a is None or a in names for a in spec):
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:  # noqa: BLE001 — constraints are an optimization only
+    covers the named axes (no-op on mesh-less CPU test runs).  A constraint
+    that fails raises."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not all(a is None or a in mesh.axis_names for a in spec):
         return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def _tp_size() -> int:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return 1
-        return mesh.shape.get("model", 1)
-    except Exception:  # noqa: BLE001
-        return 1
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else mesh.shape.get("model", 1)
 
 
 ATTN_Q_CHUNK = 512  # query-block size for memory-efficient attention
@@ -371,7 +362,10 @@ def gqa_decode(p, c: AttnConfig, x, cache, pos):
 # Pool layouts (no per-lane batch dim; capacity shared across lanes):
 #   full/window k/v: (num_blocks, block_size, KVH, hd)
 #   MLA            : c_kv (num_blocks, block_size, kv_lora),
-#                    k_rope (num_blocks, block_size, rope_dim)
+#                    k_rope (num_blocks, block_size, round_up(rope_dim, 128))
+# The k_rope pool is zero-padded to whole 128-lane tiles: Mosaic cannot DMA
+# a block out of an HBM array whose lane dim is not tile-aligned.  Padded
+# lanes are written as zeros and meet zero query lanes, so logits are exact.
 # A lane's logical block b (absolute positions [b*bs, (b+1)*bs)) lives at
 # physical block `tables[lane, b]`; block 0 is the reserved null/scratch
 # block (unmapped reads land there and are masked, inactive writes are
@@ -385,12 +379,19 @@ def paged_cache_specs(c: AttnConfig, num_blocks: int, block_size: int):
     if c.is_mla:
         return {
             "c_kv": sds((num_blocks, block_size, c.kv_lora_rank), c.dtype),
-            "k_rope": sds((num_blocks, block_size, c.rope_head_dim), c.dtype),
+            "k_rope": sds((num_blocks, block_size,
+                           round_up(c.rope_head_dim, 128)), c.dtype),
         }
     return {
         "k": sds((num_blocks, block_size, c.num_kv_heads, c.head_dim), c.dtype),
         "v": sds((num_blocks, block_size, c.num_kv_heads, c.head_dim), c.dtype),
     }
+
+
+def _pad_lanes(x, width: int):
+    """Zero-pad the last dim of `x` to `width` (the k_rope pool's lanes)."""
+    pad = width - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
 def _paged_gather(pool, tables):
@@ -524,7 +525,7 @@ def _mla_paged_attend(p, c: AttnConfig, q, ckv, kr, tables, positions,
     mode = resolve_paged_attn_mode(c.paged_mode, q, ckv, kr)
     if mode == "ref":
         ckv_seq = _paged_gather(ckv, tables)
-        kr_seq = _paged_gather(kr, tables)
+        kr_seq = _paged_gather(kr, tables)[..., :c.rope_head_dim]
         if prefill:
             mask = causal_mask(q.shape[1], ckv_seq.shape[1], positions[0])
         else:
@@ -551,7 +552,8 @@ def mla_prefill_paged(p, c: AttnConfig, x, cache, table_row, start_pos):
     q = _mla_q(p, c, x, positions)
     c_kv, k_rope = _mla_latent(p, c, x, positions)
     ckv = _paged_write_span(cache["c_kv"], table_row, start_pos, c_kv)
-    kr = _paged_write_span(cache["k_rope"], table_row, start_pos, k_rope)
+    kr = _paged_write_span(cache["k_rope"], table_row, start_pos,
+                           _pad_lanes(k_rope, cache["k_rope"].shape[-1]))
     out = _mla_paged_attend(p, c, q, ckv, kr, table_row,
                             jnp.reshape(start_pos, (1,)).astype(jnp.int32),
                             prefill=True)
@@ -564,7 +566,8 @@ def mla_decode_paged(p, c: AttnConfig, x, cache, tables, positions, active):
     ckv = _paged_write_token(cache["c_kv"], tables, positions, active,
                              c_kv_new[:, 0])
     kr = _paged_write_token(cache["k_rope"], tables, positions, active,
-                            k_rope_new[:, 0])
+                            _pad_lanes(k_rope_new[:, 0],
+                                       cache["k_rope"].shape[-1]))
     out = _mla_paged_attend(p, c, q, ckv, kr, tables, positions,
                             prefill=False)
     return out, {"c_kv": ckv, "k_rope": kr}
@@ -582,7 +585,8 @@ def mla_verify_paged(p, c: AttnConfig, x, cache, tables, positions, active,
     ckv = _paged_write_multi(cache["c_kv"], tables, positions, active,
                              nvalid, c_kv_new)
     kr = _paged_write_multi(cache["k_rope"], tables, positions, active,
-                            nvalid, k_rope_new)
+                            nvalid, _pad_lanes(k_rope_new,
+                                               cache["k_rope"].shape[-1]))
     out = _mla_paged_attend(p, c, q, ckv, kr, tables, positions,
                             prefill=False)
     return out, {"c_kv": ckv, "k_rope": kr}

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ops import dense, dense_grouped
+from repro.models.attention import ambient_constraint
 from repro.models.layers import sds
 
 
@@ -73,20 +74,6 @@ def _dispatch_groups(c: MoeConfig, T: int) -> int:
     while g > 1 and T % g:
         g //= 2
     return max(1, g)
-
-
-def _ambient_constraint(x, spec):
-    """with_sharding_constraint against the ambient mesh, if one is set."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        names = set(mesh.axis_names)
-        if not all(a is None or a in names for a in spec):
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:  # noqa: BLE001 — constraint is an optimization only
-        return x
 
 
 def _dispatch(p, c: MoeConfig, xt: jnp.ndarray, C: int):
@@ -144,9 +131,9 @@ def _expert_ffn(p, c: MoeConfig, buf: jnp.ndarray) -> jnp.ndarray:
     P = jax.sharding.PartitionSpec
     wg, wu, wd = p["w_gate"], p["w_up"], p["w_down"]
     if c.ep_mode == "tp":
-        wg = _ambient_constraint(wg, P("model", None, None))
-        wu = _ambient_constraint(wu, P("model", None, None))
-        wd = _ambient_constraint(wd, P("model", None, None))
+        wg = ambient_constraint(wg, P("model", None, None))
+        wu = ambient_constraint(wu, P("model", None, None))
+        wd = ambient_constraint(wd, P("model", None, None))
     # NB: under an ambient SPMD mesh, dense_grouped's "auto" routing falls
     # back to "ref" (ops._ambient_mesh_active) — pallas_call on these
     # EP/FSDP-sharded stacks would force XLA to all-gather them in full.
@@ -284,10 +271,7 @@ def moe_apply(p, c: MoeConfig, x: jnp.ndarray) -> jnp.ndarray:
     tests), dispatch is grouped and everything stays local."""
     B, S, D = x.shape
     T = B * S
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # noqa: BLE001
-        mesh = None
+    mesh = jax.sharding.get_abstract_mesh()
     use_sm = (_mesh_has(mesh, "data", "model")
               and c.num_experts % mesh.shape.get("model", 1) == 0
               and T % max(1, _mesh_dp_size(mesh)) == 0)

@@ -497,6 +497,7 @@ class ServingEngine:
                   for l in range(self.serve.slots)]
         paged_lane_events(
             self.obs.trace, counts, self.kv.cfg.max_blocks_per_seq,
+            block_rows=self.block_size, itemsize=self.cfg.jdtype.itemsize,
             block_bytes=self.block_size * sum(self._group_token_bytes),
             t0_us=t0, dur_us=t1 - t0, pid=PID_KERNEL)
 

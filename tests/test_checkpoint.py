@@ -69,8 +69,7 @@ class TestCheckpoint:
     def test_elastic_resume_across_meshes(self, tmp_path):
         """Save under one sharding, restore onto a different mesh — the
         elastic-rescale story (device count changed between jobs).  Runs in a
-        subprocess with 4 forced host devices; `make_mesh_compat` keeps it
-        running on both the explicit-mesh API and jax 0.4.x."""
+        subprocess with 4 forced host devices."""
         import os
         import subprocess
         import sys
@@ -83,9 +82,9 @@ class TestCheckpoint:
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.checkpoint.manager import CheckpointManager
-            from repro.launch.mesh import make_mesh_compat
-            mesh_a = make_mesh_compat((4, 1), ("data", "model"))
-            mesh_b = make_mesh_compat((2, 2), ("data", "model"))
+            from jax.sharding import AxisType
+            mesh_a = jax.make_mesh((4, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+            mesh_b = jax.make_mesh((2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             t = {{"w": jax.device_put(
                 jnp.arange(32, dtype=jnp.float32).reshape(8, 4),
                 NamedSharding(mesh_a, P("data", None)))}}

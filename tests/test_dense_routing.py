@@ -495,9 +495,11 @@ class TestTimingCache:
         try:
             assert plan_matmul_tiles(M, K, N).num_bufs != base.num_bufs
             # explicitly passed rates beat the ambient default cache
-            from repro.core.schedule import HBM_BYTES_PER_S, PEAK_FLOPS
-            explicit = plan_matmul_tiles(M, K, N, flops_per_s=PEAK_FLOPS * 2)
-            no_cache = plan_matmul_tiles(M, K, N, flops_per_s=PEAK_FLOPS * 2,
+            from repro.core.schedule import V5E
+            explicit = plan_matmul_tiles(M, K, N,
+                                         flops_per_s=V5E.peak_flops * 2)
+            no_cache = plan_matmul_tiles(M, K, N,
+                                         flops_per_s=V5E.peak_flops * 2,
                                          timing=TimingCache())
             assert explicit.num_bufs == no_cache.num_bufs
         finally:

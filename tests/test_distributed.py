@@ -4,14 +4,8 @@ These need multiple devices, so each runs in a subprocess with
 XLA_FLAGS=--xla_force_host_platform_device_count set there — the main pytest
 process keeps the default single device (smoke tests must not see 512).
 
-Meshes come from `repro.launch.mesh.make_mesh_compat` / `mesh_context`, so
-the same tests run on the post-0.4.x explicit-mesh API
-(`jax.sharding.AxisType`, `jax.set_mesh`) AND on jax 0.4.x (plain
-`jax.make_mesh` + the Mesh context manager).  Explicit `NamedSharding`s
-carry the mesh everywhere it matters; paths that detect the ambient mesh
-through the new-API registry (shard_map context parallelism, MoE explicit
-schedules) degrade to their single-program equivalents on 0.4.x, which these
-tests treat as numerically-identical fallbacks, not failures.
+Meshes are `jax.make_mesh(..., axis_types=Auto)` made ambient with
+`jax.set_mesh`.
 """
 import json
 import os
@@ -44,9 +38,9 @@ class TestStreamerDistributed:
             import jax, jax.numpy as jnp, numpy as np, re
             from jax.sharding import PartitionSpec as P, NamedSharding
             from repro.core.streamer import stream_layers, StreamSettings
-            from repro.launch.mesh import make_mesh_compat, mesh_context
+            from jax.sharding import AxisType
 
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             L, D, F, B = 6, 64, 128, 8
             key = jax.random.PRNGKey(0)
             ws = {"w1": jax.random.normal(key, (L, D, F)) * 0.05,
@@ -62,7 +56,7 @@ class TestStreamerDistributed:
                 return x + jnp.tanh(x @ w["w1"]) @ w["w2"]
 
             outs, ags = {}, {}
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 for mode in ("resident", "insitu", "naive_pp", "gpp"):
                     f = jax.jit(lambda x, ws, m=mode: stream_layers(
                         apply_fn, x, ws, L,
@@ -86,8 +80,8 @@ class TestStreamerDistributed:
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import PartitionSpec as P
             from repro.core.streamer import stream_layers, StreamSettings
-            from repro.launch.mesh import make_mesh_compat, mesh_context
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             L, D, F, B = 5, 32, 64, 4
             key = jax.random.PRNGKey(1)
             ws = {"w": jax.random.normal(key, (L, D, D)) * 0.1}
@@ -101,7 +95,7 @@ class TestStreamerDistributed:
                                   settings=StreamSettings(mode=mode, ring_depth=4),
                                   mesh=mesh, shard_specs=shard, full_specs=full)
                 return (y ** 2).mean()
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 g_res = jax.jit(jax.grad(loss), static_argnums=1)(ws, "resident")
                 g_gpp = jax.jit(jax.grad(loss), static_argnums=1)(ws, "gpp")
             np.testing.assert_allclose(np.asarray(g_gpp["w"]),
@@ -119,23 +113,23 @@ class TestContextParallelAttention:
             import jax, jax.numpy as jnp, numpy as np
             from repro.models import attention as A
             from repro.models.layers import init_from_specs
-            from repro.launch.mesh import make_mesh_compat, mesh_context
+            from jax.sharding import AxisType
 
-            mesh = make_mesh_compat((2, 4), ("data", "model"))
+            mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             cfg = A.AttnConfig(d_model=48, num_heads=6, num_kv_heads=2,
                                head_dim=8, dtype=jnp.float32)
             p = init_from_specs(A.attn_specs(cfg), jax.random.PRNGKey(0))
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 48)) * 0.5
             pos = jnp.broadcast_to(jnp.arange(64)[None], (4, 64))
             ref = A.gqa_forward(p, cfg, x, pos)
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 outp = jax.jit(lambda p, x: A.gqa_forward(p, cfg, x, pos))(p, x)
             np.testing.assert_allclose(np.asarray(outp), np.asarray(ref),
                                        rtol=2e-4, atol=2e-4)
             def loss(p, x):
                 return (A.gqa_forward(p, cfg, x, pos) ** 2).mean()
             g_ref = jax.grad(loss)(p, x)
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 g_cp = jax.jit(jax.grad(loss))(p, x)
             np.testing.assert_allclose(np.asarray(g_cp["w_q"]),
                                        np.asarray(g_ref["w_q"]),
@@ -149,15 +143,15 @@ class TestContextParallelAttention:
             import jax, jax.numpy as jnp, numpy as np
             from repro.models import attention as A
             from repro.models.layers import init_from_specs
-            from repro.launch.mesh import make_mesh_compat, mesh_context
-            mesh = make_mesh_compat((1, 4), ("data", "model"))
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh((1, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             cfg = A.AttnConfig(d_model=24, num_heads=3, num_kv_heads=1,
                                head_dim=8, window=16, dtype=jnp.float32)
             p = init_from_specs(A.attn_specs(cfg), jax.random.PRNGKey(0))
             x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 24)) * 0.5
             pos = jnp.broadcast_to(jnp.arange(64)[None], (2, 64))
             ref = A.gqa_forward(p, cfg, x, pos)
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 outp = jax.jit(lambda p, x: A.gqa_forward(p, cfg, x, pos))(p, x)
             np.testing.assert_allclose(np.asarray(outp), np.asarray(ref),
                                        rtol=2e-4, atol=2e-4)
@@ -175,7 +169,7 @@ class TestMoEShardMap:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.models import moe as M
             from repro.models.layers import init_from_specs
-            from repro.launch.mesh import make_mesh_compat, mesh_context
+            from jax.sharding import AxisType
 
             cfg = M.MoeConfig(d_model=32, d_ff=16, num_experts=8,
                               experts_per_token=2, capacity_factor=8.0,
@@ -184,7 +178,7 @@ class TestMoEShardMap:
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32)) * 0.5
 
             ref = M.moe_apply(p, cfg, x)          # no mesh -> local path
-            mesh = make_mesh_compat((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
             wsh = {
                 "router": NamedSharding(mesh, P(None, None)),
                 "w_gate": NamedSharding(mesh, P("model", "data", None)),
@@ -194,7 +188,7 @@ class TestMoEShardMap:
             psh = {k: wsh[k] for k in p}
             p_dev = jax.device_put(p, psh)
             x_dev = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 got = jax.jit(lambda p, x: M.moe_apply(p, cfg, x))(p_dev, x_dev)
             np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                        rtol=1e-4, atol=1e-4)
@@ -202,7 +196,7 @@ class TestMoEShardMap:
             def loss(p, x):
                 return (M.moe_apply(p, cfg, x) ** 2).mean()
             g_ref = jax.grad(loss)(p, x)
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 g = jax.jit(jax.grad(loss))(p_dev, x_dev)
             for k in ("router", "w_gate", "w_down"):
                 np.testing.assert_allclose(np.asarray(g[k]),
@@ -218,7 +212,7 @@ class TestStepsOnHostMesh:
         out = run_py("""
             import jax, jax.numpy as jnp
             from repro.configs.base import ShapeConfig
-            from repro.launch.mesh import make_host_mesh, mesh_context
+            from repro.launch.mesh import make_host_mesh
             from repro.launch.steps import make_train_step
             from repro.models import registry, transformer as tf
             from repro.optim import adamw
@@ -226,7 +220,7 @@ class TestStepsOnHostMesh:
             cfg = registry.get_config("gemma3-12b", smoke=True)
             mesh = make_host_mesh(2, 2)
             shape = ShapeConfig("t", 64, 8, "train")
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 b = make_train_step(cfg, mesh, shape)
                 params = jax.device_put(tf.init_params(cfg, jax.random.PRNGKey(0)),
                                         b.arg_shardings[0])
@@ -247,14 +241,14 @@ class TestStepsOnHostMesh:
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
             from repro.configs.base import ShapeConfig
-            from repro.launch.mesh import make_host_mesh, mesh_context
+            from repro.launch.mesh import make_host_mesh
             from repro.launch.steps import make_decode_step
             from repro.models import registry, transformer as tf
 
             cfg = registry.get_config("h2o-danube-1.8b", smoke=True)
             mesh = make_host_mesh(2, 2)
             shape = ShapeConfig("long", 64, 1, "decode")  # B=1 < dp size
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 b = make_decode_step(cfg, mesh, shape)
                 lowered = b.fn.lower(*b.input_specs)
                 compiled = lowered.compile()
@@ -267,7 +261,7 @@ class TestStepsOnHostMesh:
             import jax, jax.numpy as jnp, numpy as np
             from repro.configs.base import ShapeConfig
             from repro.core.streamer import StreamSettings
-            from repro.launch.mesh import make_host_mesh, mesh_context
+            from repro.launch.mesh import make_host_mesh
             from repro.launch.steps import make_train_step
             from repro.models import registry, transformer as tf
             from repro.optim import adamw
@@ -276,7 +270,7 @@ class TestStepsOnHostMesh:
                 stream=StreamSettings(mode="gpp", ring_depth=3))
             mesh = make_host_mesh(2, 2)
             shape = ShapeConfig("t", 64, 8, "train")
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 b = make_train_step(cfg, mesh, shape)
                 params = jax.device_put(tf.init_params(cfg, jax.random.PRNGKey(0)),
                                         b.arg_shardings[0])

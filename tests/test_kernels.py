@@ -282,8 +282,9 @@ class TestSchedule:
         3-D grid: every (step, chunk) must be DMA'd exactly once, at or
         before the step that computes on it — including across n/k/m
         tile-loop boundaries and short (sub-ramp) grids."""
-        for G in (1, 2, 3, 4, 6):
-            C = max(1, G - 1)
+        for G, C in [(1, 1), (2, 1), (3, 2), (4, 3), (6, 5),
+                     # C capped below G-1 by whole sublane tiles
+                     (4, 1), (6, 2), (8, 4)]:
             for grid in [(1, 1, 1), (1, 2, 3), (2, 3, 2), (1, G, 1),
                          (3, 1, 1), (2, 2, G + 2)]:
                 S = grid[0] * grid[1] * grid[2]
@@ -305,6 +306,25 @@ class TestSchedule:
                 assert spans[0][0] == 0 and spans[-1][1] == K
                 for (a, b), (c, d) in zip(spans, spans[1:]):
                     assert b == c
+
+    def test_ring_chunks_are_whole_sublane_tiles(self):
+        """Mosaic DMAs slice rows in whole (sublane, 128-lane) tiles: every
+        chunk of a tile-multiple block is non-empty and tile-aligned."""
+        from repro.kernels.gpp_matmul import ring_chunks, sublane_tile
+        assert [sublane_tile(n) for n in (4, 2, 1)] == [8, 16, 32]
+        for itemsize in (4, 2, 1):
+            tile = sublane_tile(itemsize)
+            for rows in (tile, 2 * tile, 128, 1024, 2048):
+                for G in range(1, 9):
+                    C = ring_chunks(G, rows, tile)
+                    assert 1 <= C <= max(1, G - 1)
+                    spans = [_chunk_bounds(rows, C, c, tile)
+                             for c in range(C)]
+                    assert spans[0][0] == 0 and spans[-1][1] == rows
+                    for lo, hi in spans:
+                        assert hi > lo and lo % tile == 0 and hi % tile == 0
+        # a block that is not a tile multiple moves whole
+        assert ring_chunks(8, 24, 16) == 1 and ring_chunks(8, 16, 32) == 1
 
     def test_flat_bandwidth_bytes_per_step(self):
         """Steady-state issued bytes per grid step == exactly one tile, even

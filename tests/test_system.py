@@ -99,11 +99,12 @@ class TestKernelSystemIntegration:
     def test_kernel_ring_depth_from_paper_model(self):
         """The kernel's auto ring depth equals ceil(t_dma/t_cmp)+1 from the
         paper's timing model with TPU constants."""
-        from repro.kernels.ops import HBM_BYTES_PER_S, PEAK_FLOPS, plan_ring_depth
+        from repro.core.schedule import V5E
+        from repro.kernels.ops import plan_ring_depth
         for M in (8, 64, 512):
             K = bn = 256
-            t_dma = (K * bn * 2) / HBM_BYTES_PER_S
-            t_cmp = (2 * M * K * bn) / PEAK_FLOPS
+            t_dma = (K * bn * 2) / V5E.hbm_bytes_per_s
+            t_cmp = (2 * M * K * bn) / V5E.peak_flops
             expect = min(8, max(2, math.ceil(t_dma / t_cmp) + 1))
             assert plan_ring_depth(M, K, bn) == expect
 

@@ -1,0 +1,190 @@
+"""Compile rehearsal for a TPU v5e chip, without the chip.
+
+The TPU compiler is installed with jaxlib, so every kernel of the serving
+path can be lowered and compiled for a *described* v5e
+(`topologies.get_topology_desc`) while JAX itself runs on the CPU.  That
+catches what interpret mode cannot: DMA slices not aligned to the VMEM
+tiling, reshapes Mosaic cannot lay out, and working sets over the scoped
+VMEM limit.  Nothing runs, so these tests say nothing about results or
+speed.
+
+The topology is described inside a module-scope fixture, never at import:
+only one process may hold the TPU library, and every pytest-xdist worker
+imports this file.  The kernels ask `jax.devices()` for the chip they plan
+against, which here is the CPU, so the fixture points their chip lookup at
+the described device's kind.
+"""
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
+from repro.core.schedule import chip_spec
+
+HBM_BYTES = 16 * 2**30          # one v5e chip
+D, FF, VOCAB = QWEN.d_model, QWEN.d_ff, QWEN.vocab_size
+HEADS, HD = QWEN.num_heads, QWEN.d_model // QWEN.num_heads
+BS, SLOTS, MAX_LEN = 16, 8, 2048
+MB = MAX_LEN // BS
+NB = SLOTS * MB + 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")    # else the compiler logs to /tmp
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    import repro.kernels.gpp_matmul as gm
+    import repro.kernels.ops as ops
+    import repro.kernels.paged_attention as pa
+
+    spec = chip_spec(topo.devices[0].device_kind)
+    # a described chip's executables can be written to the persistent cache
+    # but not read back without the chip: keep the cache off meanwhile
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (gm, pa, ops):
+            mp.setattr(mod, "target_chip", lambda interpret: spec)
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ------------------------------------------------------------ gpp_matmul
+
+@pytest.mark.parametrize("G", range(1, 9))
+@pytest.mark.parametrize("M", [4, 8, 256])
+def test_gpp_matmul_ring_depths(one_chip, M, G):
+    """Decode (M 4-8) and prefill-chunk M at every ring depth: the chunk
+    DMAs must stay aligned to the bf16 sublane tile."""
+    from repro.kernels.gpp_matmul import gpp_matmul
+    _compile(lambda x, w: gpp_matmul(x, w, num_bufs=G),
+             _sds(one_chip, (M, D)), _sds(one_chip, (D, FF)))
+
+
+@pytest.mark.parametrize("M,K,N", [
+    (8, D, VOCAB),          # tied LM head at decode
+    (4096, D, FF),          # long prefill: a working set over the default
+                            # scoped VMEM limit
+    (8, FF, D),             # MLP down-projection, K split into tiles
+])
+def test_gpp_matmul_planned(one_chip, M, K, N):
+    from repro.kernels.gpp_matmul import gpp_matmul
+    _compile(gpp_matmul, _sds(one_chip, (M, K)), _sds(one_chip, (K, N)))
+
+
+def test_gpp_matmul_grouped_moe(one_chip):
+    """deepseek-v2-lite routed experts: 64 experts, d 2048, d_ff 1408."""
+    from repro.kernels.gpp_matmul import gpp_matmul_grouped
+    _compile(gpp_matmul_grouped, _sds(one_chip, (64, 16, 2048)),
+             _sds(one_chip, (64, 2048, 1408)))
+
+
+# ------------------------------------------------------- paged_attention
+
+@pytest.mark.parametrize("B,S,window", [
+    (SLOTS, 1, None),       # decode
+    (1, 256, None),         # prefill chunk
+    (SLOTS, 5, None),       # speculative verify
+    (SLOTS, 1, 1024),       # sliding window
+])
+def test_paged_attention_gqa(one_chip, B, S, window):
+    from repro.kernels.paged_attention import paged_attention
+    kv = _sds(one_chip, (NB, BS, HEADS, HD))
+    _compile(lambda q, a, b, t, p: paged_attention(
+                 q, a, b, t, p, num_kv_heads=HEADS, scale=HD ** -0.5,
+                 window=window),
+             _sds(one_chip, (B, S, HEADS, HD)), kv, kv,
+             _sds(one_chip, (B, MB), jnp.int32),
+             _sds(one_chip, (B,), jnp.int32))
+
+
+@pytest.mark.parametrize("B,S", [(SLOTS, 1), (1, 128)])
+def test_paged_attention_mla(one_chip, B, S):
+    """deepseek-v2-lite latent pools: c_kv 512 lanes, k_rope 64 lanes
+    zero-padded to one 128-lane tile (`paged_cache_specs`)."""
+    from repro.kernels.ops import paged_attn
+    _compile(lambda q, a, b, t, p: paged_attn(
+                 q, a, b, t, p, num_kv_heads=1, scale=192 ** -0.5, mla=True,
+                 mode="pallas"),
+             _sds(one_chip, (B, S, 16, 512 + 64)),
+             _sds(one_chip, (NB, BS, 512)), _sds(one_chip, (NB, BS, 128)),
+             _sds(one_chip, (B, MB), jnp.int32),
+             _sds(one_chip, (B,), jnp.int32))
+
+
+# ------------------------------------------------------ whole serve step
+
+def _on_chip(sharding, tree):
+    return jax.tree.map(lambda s: _sds(sharding, s.shape, s.dtype), tree)
+
+
+def _full_width(sharding):
+    """qwen1.5-0.5b with both kernels forced on: (cfg, params, caches)."""
+    from repro.models import transformer as tf
+    cfg = QWEN.with_(dense_kernel="kernel", paged_attn_kernel="pallas")
+    params = _on_chip(sharding, jax.eval_shape(
+        lambda: tf.init_params(cfg, jax.random.PRNGKey(0))))
+    return cfg, params, _on_chip(sharding, tf.paged_cache_specs(cfg, NB, BS))
+
+
+def _assert_fits(compiled):
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB > 16 GiB"
+
+
+def test_decode_step_paged_fits_one_chip(one_chip):
+    """The full-width qwen1.5-0.5b decode step with both kernels on: 8
+    lanes x 2048 tokens of paged KV must fit one chip's HBM."""
+    from repro.models import transformer as tf
+    cfg, params, caches = _full_width(one_chip)
+    tables = tuple(_sds(one_chip, (SLOTS, MB), jnp.int32)
+                   for _ in tf.layer_group_keys(cfg))
+    _assert_fits(_compile(
+        lambda p, c, tok, tb, pos, act: tf.decode_step_paged(
+            p, cfg, tok, c, tb, pos, act),
+        params, caches, _sds(one_chip, (SLOTS, 1), jnp.int32), tables,
+        _sds(one_chip, (SLOTS,), jnp.int32),
+        _sds(one_chip, (SLOTS,), jnp.bool_)))
+
+
+def test_prefill_chunk_fits_one_chip(one_chip):
+    """One full-width prefill chunk (the engine's planned 32 tokens)."""
+    from repro.models import transformer as tf
+    cfg, params, caches = _full_width(one_chip)
+    rows = tuple(_sds(one_chip, (1, MB), jnp.int32)
+                 for _ in tf.layer_group_keys(cfg))
+    scalar = _sds(one_chip, (), jnp.int32)
+    _assert_fits(_compile(
+        lambda p, c, tok, row, start, last: tf.prefill_chunk(
+            p, cfg, tok, c, row, start, last),
+        params, caches, _sds(one_chip, (1, 32), jnp.int32), rows, scalar,
+        scalar))
