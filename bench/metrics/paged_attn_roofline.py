@@ -1,0 +1,20 @@
+"""paged_attention's share of its roofline over the traced span: the least
+time of every call (live KV read once, bench/work.py) over the kernel's
+device time, with the ops that staged its operands into VMEM.  Nothing is
+read when the trace's calls do not match the step log's."""
+from bench import trace_reduce
+from bench.stats import on_device, traced_work
+
+
+def read(ctx):
+    if not on_device(ctx):
+        return None
+    tot, n = trace_reduce.staged_kernel_time(ctx.trace, "paged_attn")
+    pre, dec = traced_work(ctx)
+    calls = (len(pre) + len(dec)) * ctx.shape.layers
+    if not n or n != calls:
+        return None
+    p = ctx.peaks
+    least = sum(w["attn"].least_s(p.flops_per_s, p.hbm_bytes_per_s)
+                for w in pre + dec)
+    return 100.0 * least / (tot / 1e9)
