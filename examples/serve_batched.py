@@ -87,8 +87,8 @@ def main():
 
     # ---- observability: demo trace + metrics snapshot --------------------
     # obs=True turns on the telemetry layer (docs/OBSERVABILITY.md): async
-    # request spans, per-step/phase spans, modeled kernel DMA/compute
-    # lanes, and TTFT/TPOT histograms — the token stream is unchanged.
+    # request spans, per-step and per-stage spans, and TTFT/TPOT
+    # histograms — the token stream is unchanged.
     eng = ServingEngine(cfg, params, ServeConfig(slots=2, max_len=96,
                                                  obs=True))
     for _ in range(4):

@@ -108,9 +108,10 @@ doc = json.load(open(os.path.join(d, "trace.json")))
 evs = doc["traceEvents"]
 assert evs, "empty trace"
 pids = {e["pid"] for e in evs}
-assert {1, 2, 3} <= pids, f"missing trace tracks: {pids}"   # serving/requests/kernel
+assert pids == {1, 2}, f"trace tracks: {pids}"   # serving/requests
 assert any(e.get("ph") == "X" and e["name"] == "step" for e in evs)
-assert any(e.get("cat") == "modeled" for e in evs), "no kernel lanes"
+assert any(e.get("cat") == "phase" and e["name"] == "serve.decode.fetch"
+           for e in evs), "no engine phases"
 (line,) = open(os.path.join(d, "metrics.jsonl")).read().splitlines()[-1:]
 snap = json.loads(line)
 req = snap["requests"]

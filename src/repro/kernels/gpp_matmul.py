@@ -126,95 +126,6 @@ def chunk_issue_schedule(num_steps: int, G: int,
     return issued
 
 
-def schedule_lane_events(trace, *, num_steps: int, G: int, C: int,
-                         t0_us: float, dur_us: float, step_bytes: float = 0.0,
-                         live=None, pid: int = 0, tid_dma: int = 0,
-                         tid_compute: int = 1, max_events: int = 256,
-                         name: str = "gpp") -> int:
-    """Render the chunk-issue schedule as DMA-vs-compute trace lanes.
-
-    Host-side observability companion to `_run_chunk_schedule`: replays
-    `chunk_issue_schedule(num_steps, G, C)` — the exact issue pattern the
-    kernel executes — and emits two lanes of Chrome trace-event "X" spans
-    into `trace` (an `obs.trace.TraceRecorder`), scaled into the measured
-    call window [t0_us, t0_us + dur_us]:
-
-      tid_dma      chunk DMAs *started* per grid step (count + bytes — flat
-                   at one tile per step once the ring is primed: the paper's
-                   invariant, now visible on a timeline)
-      tid_compute  the grid step's compute occupancy
-
-    Timebase: the window is split evenly across LIVE grid steps (`live(s)`
-    false ⇒ the step is skipped by the kernel's predicate and costs ~no
-    time); a real per-step clock can't exist inside a Pallas body, so these
-    lanes are a schedule-exact model stretched over the measured wall
-    window — events carry cat="modeled" to say so.  Steps coalesce into at
-    most `max_events` buckets per lane so long grids stay cheap to record.
-    Returns the number of events emitted.
-    """
-    if not getattr(trace, "enabled", False) or num_steps <= 0 or dur_us <= 0:
-        return 0
-    sched = chunk_issue_schedule(num_steps, G, C)
-    starts = [0] * num_steps            # chunk DMAs issued at each step
-    for (step, chunk), at in sched.items():
-        if live is None or live(step):
-            for s in at:
-                starts[s] += 1
-    is_live = [bool(live(s)) if live is not None else True
-               for s in range(num_steps)]
-    n_live = sum(is_live)
-    if n_live == 0:
-        return 0
-    dt = dur_us / n_live
-    chunk_bytes = step_bytes / C if C else 0.0
-    bucket = max(1, -(-num_steps // max_events))
-    emitted = 0
-    t = t0_us                           # start of the current bucket
-    for b0 in range(0, num_steps, bucket):
-        b1 = min(b0 + bucket, num_steps)
-        live_in = sum(is_live[b0:b1])
-        chunks = sum(starts[b0:b1])
-        width = live_in * dt
-        label = (f"{name} step {b0}" if bucket == 1
-                 else f"{name} steps {b0}-{b1 - 1}")
-        if chunks:
-            trace.complete(
-                f"{label} dma", t, width or dt * 0.1, pid=pid, tid=tid_dma,
-                cat="modeled",
-                args={"chunks_started": chunks,
-                      "bytes": chunks * chunk_bytes, "ring": G})
-            emitted += 1
-        if live_in:
-            trace.complete(
-                f"{label} compute", t, width, pid=pid, tid=tid_compute,
-                cat="modeled",
-                args={"grid_steps": b1 - b0, "live_steps": live_in})
-            emitted += 1
-        t += width
-    return emitted
-
-
-def matmul_lane_events(trace, M: int, K: int, N: int, *,
-                       itemsize: int = 4, t0_us: float, dur_us: float,
-                       pid: int = 0, max_events: int = 256) -> int:
-    """Schedule-exact DMA/compute lanes for one `gpp_matmul(M,K,N)` call:
-    plans the same tiles/ring the kernel wrapper would (for the attached
-    TPU, or the v5e entry off-TPU) and replays the chunk schedule into
-    `trace` over the measured window."""
-    plan = plan_matmul_tiles(
-        M, K, N, x_itemsize=itemsize, w_itemsize=itemsize,
-        out_itemsize=itemsize,
-        chip=target_chip(interpret=jax.default_backend() != "tpu"))
-    num_m, num_n, num_k = plan.grid(M, N, K)
-    steps = num_m * num_n * num_k
-    G = min(plan.num_bufs, max(1, steps))
-    C = ring_chunks(G, plan.block_k, sublane_tile(itemsize))
-    return schedule_lane_events(
-        trace, num_steps=steps, G=G, C=C, t0_us=t0_us, dur_us=dur_us,
-        step_bytes=plan.block_k * plan.block_n * itemsize,
-        pid=pid, max_events=max_events, name="matmul")
-
-
 def _make_chunk_ops(ring, sems, G: int, C: int, bk: int, tile_slice):
     """(start_chunk, wait_chunk) pair for the ring's chunk DMAs, shared by
     the flat and grouped kernels.  `tile_slice(step, lo, hi)` returns the
@@ -440,6 +351,7 @@ def gpp_matmul(
     )
     y = pl.pallas_call(
         kernel,
+        name="gpp_matmul",
         grid=(num_m, num_n, num_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
@@ -640,6 +552,7 @@ def gpp_matmul_grouped(
     )
     y = pl.pallas_call(
         kernel,
+        name="gpp_matmul_grouped",
         grid=(E, num_m, num_n, num_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, m, n, k: (e, m, n)),

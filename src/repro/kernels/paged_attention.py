@@ -77,39 +77,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import plan_paged_attn
 from repro.kernels.gpp_matmul import (_make_chunk_ops, _run_chunk_schedule,
-                                      ring_chunks, schedule_lane_events,
-                                      sublane_tile, target_chip)
+                                      ring_chunks, sublane_tile, target_chip)
 
 NEG_INF = float("-inf")
-
-
-def paged_lane_events(trace, live_counts: "list[int]", max_blocks: int, *,
-                      block_rows: int, itemsize: int, G: int = 4,
-                      block_bytes: int = 0, t0_us: float, dur_us: float,
-                      pid: int = 0, max_events: int = 128) -> int:
-    """DMA/compute trace lanes for one paged-attention call.
-
-    Replays the kernel's lane-major (B, MB) grid — `live_counts[lane]` live
-    logical blocks out of `max_blocks` table entries per lane, exactly what
-    the in-kernel `live()` predicate admits for prefix-visible attention —
-    through the shared chunk-issue schedule and renders it into `trace`
-    over the measured call window.  Dead steps (blocks past a lane's
-    position) cost the kernel neither DMA nor compute, so they get zero
-    width on the modeled timebase; see
-    `kernels.gpp_matmul.schedule_lane_events`.  `block_rows` (tokens per
-    KV block) and `itemsize` (pool dtype) set the chunk count exactly as the
-    kernel does."""
-    B = len(live_counts)
-    steps = B * max_blocks
-    if steps <= 0:
-        return 0
-    G = min(G, steps)
-    C = ring_chunks(G, block_rows, sublane_tile(itemsize))
-    return schedule_lane_events(
-        trace, num_steps=steps,
-        G=G, C=C, t0_us=t0_us, dur_us=dur_us, step_bytes=block_bytes,
-        live=lambda s: (s % max_blocks) < live_counts[s // max_blocks],
-        pid=pid, max_events=max_events, name="kv")
 
 
 def _split_heads(tile, kvh: int):
@@ -370,6 +340,7 @@ def paged_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, rS, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -3,8 +3,9 @@
 Three pillars, one handle:
 
   `obs.trace`    span/event recorder -> Chrome/Perfetto trace-event JSON
-                 (`obs/trace.py`): request lifecycles, engine step phases,
-                 modeled kernel DMA-vs-compute lanes.
+                 (`obs/trace.py`): request lifecycles and engine step
+                 phases; `phase` puts the same phases on the profiler's
+                 clock as `jax.profiler` annotations.
   `obs.ledger`   typed per-step HBM-byte ledger (`obs/ledger.py`): THE
                  step-metrics schema both engines emit, with bounded
                  retention and a simulate_gpp predicted-vs-measured
@@ -27,18 +28,16 @@ import time
 from repro.obs.ledger import STEP_SCHEMA, BandwidthLedger, step_row
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                RequestTracker, percentile)
-from repro.obs.trace import (NULL_TRACE, PID_KERNEL, PID_REQUESTS,
-                             PID_SERVING, TID_COMPUTE, TID_DMA, TID_ENGINE,
-                             TID_LANE0, TraceRecorder,
-                             annotate_serving_tracks)
+from repro.obs.trace import (NULL_TRACE, PID_REQUESTS, PID_SERVING,
+                             TID_ENGINE, TID_LANE0, TraceRecorder,
+                             annotate_serving_tracks, phase)
 
 __all__ = [
     "STEP_SCHEMA", "BandwidthLedger", "step_row",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "RequestTracker",
     "percentile",
-    "NULL_TRACE", "TraceRecorder", "annotate_serving_tracks",
-    "PID_SERVING", "PID_REQUESTS", "PID_KERNEL",
-    "TID_ENGINE", "TID_LANE0", "TID_DMA", "TID_COMPUTE",
+    "NULL_TRACE", "TraceRecorder", "annotate_serving_tracks", "phase",
+    "PID_SERVING", "PID_REQUESTS", "TID_ENGINE", "TID_LANE0",
     "Telemetry", "make_telemetry",
 ]
 

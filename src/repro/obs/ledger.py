@@ -12,12 +12,16 @@ ledger is the single typed source of truth:
     derives the composites, and rejects unknown keys — schema drift between
     the engines is now a constructor error, not a silently diverging dict.
   * Byte components per step: `param_bytes` (the weight stream — the
-    paper's "rewrite" traffic), `kv_write_bytes` / `kv_read_bytes` (KV
+    paper's "rewrite" traffic — once per model program the step launched,
+    so a step that runs a prefill chunk and a decode call streams the
+    weights twice), `kv_write_bytes` / `kv_read_bytes` (KV
     traffic proportional to processed/visible tokens), the two
     attention-read paths (`attn_bytes_gather` materialized vs
     `attn_bytes_stream` DMA'd by the Pallas ring), plus the two savings
     columns: `prefix_saved_bytes` (KV writes the radix cache skipped) and
     `spec_saved_bytes` (weight streams amortized by accepted draft tokens).
+  * `d2h_bytes`: what the step copied device -> host (the logits it
+    samples from), counted at the site that reads each array back.
   * The composite `hbm_bytes = param_bytes + kv_write_bytes + kv_read_bytes`
     reproduces the previous hand-built projection exactly (regression-
     tested in tests/test_obs.py), so the BENCH_serving.json trajectory
@@ -55,6 +59,8 @@ STEP_SCHEMA: "tuple[str, ...]" = (
     "param_bytes", "kv_write_bytes", "kv_read_bytes",
     "prefix_saved_bytes", "spec_saved_bytes",
     "hbm_bytes", "attn_bytes_gather", "attn_bytes_stream",
+    # device -> host copies (bytes of every array the step read back)
+    "d2h_bytes",
     # wall time of the step (us; 0.0 when telemetry is disabled)
     "step_wall_us",
 )
@@ -68,8 +74,6 @@ def step_row(**fields) -> dict:
     Derived when not explicitly passed:
       acceptance_rate = accepted/drafted (0 when nothing drafted)
       hbm_bytes       = param_bytes + kv_write_bytes + kv_read_bytes
-      spec_saved_bytes = accepted_tokens * param_bytes (each accepted draft
-                         token is one decode step's weight stream avoided)
     """
     unknown = set(fields) - _SCHEMA_SET
     if unknown:
@@ -84,8 +88,6 @@ def step_row(**fields) -> dict:
     if "hbm_bytes" not in fields:
         row["hbm_bytes"] = (row["param_bytes"] + row["kv_write_bytes"]
                             + row["kv_read_bytes"])
-    if "spec_saved_bytes" not in fields:
-        row["spec_saved_bytes"] = row["accepted_tokens"] * row["param_bytes"]
     return row
 
 
@@ -207,6 +209,6 @@ class BandwidthLedger:
             "drafted_tokens", "accepted_tokens", "prefix_hit_tokens",
             "param_bytes", "kv_write_bytes", "kv_read_bytes",
             "prefix_saved_bytes", "spec_saved_bytes", "hbm_bytes",
-            "attn_bytes_gather", "attn_bytes_stream")})
+            "attn_bytes_gather", "attn_bytes_stream", "d2h_bytes")})
         out.update(self.utilization_report())
         return out

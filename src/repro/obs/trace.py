@@ -1,11 +1,12 @@
-"""Span/event recorder exporting Chrome/Perfetto trace-event JSON.
+"""Span/event recorder exporting Chrome/Perfetto trace-event JSON, and the
+engine's phase spans on the profiler's clock.
 
 The paper's argument is a bandwidth-utilization *timeline* — rewrite and
 compute activity laid out against wall time so the flat-traffic property is
-visible, not just summarized.  This module is that timeline for the repo:
-a `TraceRecorder` collects events into a bounded ring buffer and exports
-them in the Chrome trace-event format (``{"traceEvents": [...]}``), which
-Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` both load
+visible, not just summarized.  This module is the host side of that
+timeline: a `TraceRecorder` collects events into a bounded ring buffer and
+exports them in the Chrome trace-event format (``{"traceEvents": [...]}``),
+which Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` both load
 directly.
 
 Design constraints, in order:
@@ -31,6 +32,12 @@ Event vocabulary (Chrome trace-event phases used):
   ``M``   metadata: process/thread names for the Perfetto track labels
 
 All timestamps in the export are microseconds (the trace-event unit).
+
+`phase` is the one span API of the serving engine.  Its spans are
+`jax.profiler.TraceAnnotation`s, so under `jax.profiler.trace` they land
+in the same profile as the device's operations, where the device's idle
+time can be put down to the stage the host was in; with telemetry on they
+are also recorded here.
 """
 from __future__ import annotations
 
@@ -38,6 +45,8 @@ import json
 import time
 from collections import deque
 from contextlib import contextmanager
+
+import jax
 
 
 class TraceRecorder:
@@ -216,18 +225,35 @@ class _NullTrace:
 NULL_TRACE = _NullTrace()
 
 # Fixed pid layout for the serving instrumentation so every exported trace
-# lands request/engine/kernel activity on the same named tracks.
+# lands request and engine activity on the same named tracks.
 PID_SERVING = 1    # engine steps (tid 0) + one tid per lane (TID_LANE0 + i)
 PID_REQUESTS = 2   # async request-lifecycle spans keyed by rid
-PID_KERNEL = 3     # chunk-issue schedule lanes: tid 0 = DMA, tid 1 = compute
 TID_ENGINE = 0
 TID_LANE0 = 10
-TID_DMA = 0
-TID_COMPUTE = 1
+
+
+def phase(trace, name: str, *, tid: int = TID_ENGINE, **args):
+    """A span around one stage of the serving engine, on the profiler's
+    clock: a `jax.profiler.TraceAnnotation` named `name` (with `args` as
+    its metadata), which costs about a microsecond and records nothing
+    unless a profiler session is running.  With telemetry on (`trace` a
+    `TraceRecorder`), the same span is also emitted as an ``X`` event on
+    the serving track `tid`, so an exported trace keeps the engine's
+    phases."""
+    if not trace.enabled:
+        return jax.profiler.TraceAnnotation(name, **args)
+    return _recorded_phase(trace, name, tid, args)
+
+
+@contextmanager
+def _recorded_phase(trace, name, tid, args):
+    with jax.profiler.TraceAnnotation(name, **args), trace.span(
+            name, pid=PID_SERVING, tid=tid, args=args or None, cat="phase"):
+        yield
 
 
 def annotate_serving_tracks(trace: "TraceRecorder", slots: int) -> None:
-    """Name the fixed serving/kernel tracks on a fresh recorder."""
+    """Name the fixed serving tracks on a fresh recorder."""
     if not trace.enabled:
         return
     trace.name_process(PID_SERVING, "serving engine")
@@ -235,6 +261,3 @@ def annotate_serving_tracks(trace: "TraceRecorder", slots: int) -> None:
     for lane in range(slots):
         trace.name_thread(PID_SERVING, TID_LANE0 + lane, f"lane {lane}")
     trace.name_process(PID_REQUESTS, "requests")
-    trace.name_process(PID_KERNEL, "kernel chunk schedule")
-    trace.name_thread(PID_KERNEL, TID_DMA, "DMA lane (HBM->VMEM)")
-    trace.name_thread(PID_KERNEL, TID_COMPUTE, "compute lane")

@@ -32,8 +32,8 @@ from repro.models import transformer as tf
 from repro.obs import make_telemetry
 from repro.obs.ledger import BandwidthLedger
 from repro.obs.trace import (PID_REQUESTS, PID_SERVING, TID_ENGINE,
-                             TID_LANE0, annotate_serving_tracks)
-from repro.serving.engine import ServeConfig, sample_token
+                             TID_LANE0, annotate_serving_tracks, phase)
+from repro.serving.engine import DRAWN_ID_BYTES, ServeConfig, sample_token
 
 Pytree = Any
 
@@ -68,6 +68,8 @@ class DenseServingEngine:
             serve.metrics_retention if serve.metrics_retention is not None
             else cfg.metrics_retention))
         self._param_bytes = cfg.active_params() * cfg.jdtype.itemsize
+        self._programs = 0             # model programs launched this step
+        self._d2h = 0                  # device -> host bytes this step
         self._kv_token_bytes = 0       # measured from the first prefill's
         #                                materialized cache (recurrent state
         #                                amortized over max_len)
@@ -119,6 +121,15 @@ class DenseServingEngine:
         return tokens_per_step_cov([m["tokens"] for m in self.metrics])
 
     # ------------------------------------------------------------ engine
+    def _sample(self, rid: int, token_idx: int, logits_row) -> int:
+        """Read one lane's logits back (counted into the step's
+        `d2h_bytes`) and sample the next token from them."""
+        self._d2h += logits_row.nbytes
+        row = np.asarray(logits_row, np.float32)
+        if self.serve.temperature > 0.0:
+            self._d2h += DRAWN_ID_BYTES
+        return sample_token(self.serve, rid, token_idx, row)
+
     def _admit(self) -> int:
         """Fill idle lanes from the queue (continuous batching).  Returns
         prefill tokens processed (whole prompts — the bursty phase)."""
@@ -127,15 +138,12 @@ class DenseServingEngine:
             if lane.request_id is not None or not self._queue:
                 continue
             rid, prompt, max_new = self._queue.pop(0)
-            t0 = self.obs.now_us() if self.obs.enabled else 0.0
-            logits, caches = self._prefill(self.params, prompt[None, :])
-            if self.obs.enabled:
-                self.obs.trace.complete(
-                    "prefill", t0, self.obs.now_us() - t0, pid=PID_SERVING,
-                    tid=TID_LANE0 + i, cat="phase",
-                    args={"rid": rid, "tokens": len(prompt)})
+            with phase(self.obs.trace, "serve.prefill.launch",
+                       tid=TID_LANE0 + i, rid=rid, rows=len(prompt)):
+                logits, caches = self._prefill(self.params, prompt[None, :])
+            self._programs += 1
             prefill_tokens += len(prompt)
-            first = sample_token(self.serve, rid, 0, logits[0, -1])
+            first = self._sample(rid, 0, logits[0, -1])
             self.obs.requests.on_first_token(rid)
             if max_new <= 1 or (self.serve.eos_token is not None
                                 and first == self.serve.eos_token):
@@ -178,9 +186,12 @@ class DenseServingEngine:
         keys to the paged engine; paged-only fields stay at the schema's
         zero defaults: this engine never shares KV, never speculates, and
         has no block pool).  Byte components are real, not parity zeros:
-        weights stream once per step, processed tokens write cache state,
+        weights stream once per model program (each prefill, each decode
+        call of a position group), processed tokens write cache state,
         reads cover each participant's visible context."""
         tokens = prefill_tokens + decode_tokens
+        programs, self._programs = self._programs, 0
+        d2h, self._d2h = self._d2h, 0
         row = self.metrics.record(
             tokens=tokens,
             prefill_tokens=prefill_tokens,
@@ -188,9 +199,10 @@ class DenseServingEngine:
             prefill_real_tokens=prefill_tokens,
             decode_tokens=decode_tokens,
             queue_depth=len(self._queue),
-            param_bytes=self._param_bytes,
+            param_bytes=programs * self._param_bytes,
             kv_write_bytes=tokens * self._kv_token_bytes,
             kv_read_bytes=read_tokens * self._kv_token_bytes,
+            d2h_bytes=d2h,
             step_wall_us=(self.obs.now_us() - step_t0
                           if self.obs.enabled else 0.0),
         )
@@ -234,14 +246,11 @@ class DenseServingEngine:
         decode_tokens = 0
         read_tokens = prefill_tokens   # prefill self-attends its context
         for pos, lanes_at in pos_groups.items():
-            t0 = obs.now_us() if obs.enabled else 0.0
-            logits, new_caches = self._decode(
-                self.params, jnp.asarray(toks), self.caches, pos)
-            if obs.enabled:
-                obs.trace.complete(
-                    "decode", t0, obs.now_us() - t0, pid=PID_SERVING,
-                    tid=TID_ENGINE, cat="phase",
-                    args={"pos": pos, "lanes": len(lanes_at)})
+            with phase(obs.trace, "serve.decode.launch", pos=pos,
+                       lanes=len(lanes_at)):
+                logits, new_caches = self._decode(
+                    self.params, jnp.asarray(toks), self.caches, pos)
+            self._programs += 1
             in_group = np.zeros((self.serve.slots,), bool)
             in_group[lanes_at] = True
 
@@ -255,8 +264,8 @@ class DenseServingEngine:
                 merge, self.caches, new_caches)
             for i in lanes_at:
                 lane = self.lanes[i]
-                nxt = sample_token(self.serve, lane.request_id,
-                                   len(lane.tokens), logits[i, -1])
+                nxt = self._sample(lane.request_id, len(lane.tokens),
+                                   logits[i, -1])
                 lane.tokens.append(nxt)
                 lane.pos += 1
                 lane.remaining -= 1

@@ -43,8 +43,14 @@ of lane assignment, step interleaving, preemption/resume, or prefix-cache
 hits.
 
 Per-step metrics (tokens, blocks in use/shared, prefix hit tokens, queue
-depth, projected HBM bytes) accumulate in `engine.metrics`;
-`benchmarks/run.py` records them into BENCH_serving.json.
+depth, projected HBM bytes, device -> host bytes) accumulate in
+`engine.metrics`; `benchmarks/run.py` records them into BENCH_serving.json.
+Each step runs inside a `serve.step` profiler span numbered like its ledger
+row, with a child span per stage (`serve.schedule`, `serve.copy`,
+`serve.{prefill,decode,verify}.{launch,fetch}`, `serve.sample`,
+`serve.account`; a `*.dispatch` span inside each launch covers the jitted
+call alone), so a `jax.profiler` trace puts the device's idle time inside
+a step down to what the host was doing.
 
 Recurrent architectures (mamba/xlstm blocks: O(1) state, no paged KV) are
 served by `serving.dense_engine.DenseServingEngine` — see `make_engine`.
@@ -62,13 +68,11 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.schedule import plan_serve_chunk, round_up, tokens_per_step_cov
-from repro.kernels.gpp_matmul import matmul_lane_events
-from repro.kernels.paged_attention import paged_lane_events
 from repro.models import transformer as tf
 from repro.obs import make_telemetry
 from repro.obs.ledger import BandwidthLedger
-from repro.obs.trace import (PID_KERNEL, PID_REQUESTS, PID_SERVING,
-                             TID_ENGINE, TID_LANE0, annotate_serving_tracks)
+from repro.obs.trace import (PID_REQUESTS, PID_SERVING, TID_ENGINE,
+                             TID_LANE0, annotate_serving_tracks, phase)
 from repro.serving.cache import GroupedPagedCache, PagedKVCache
 from repro.serving.prefix import PrefixCache, ngram_propose
 from repro.serving.scheduler import ChunkedPrefillScheduler, Request
@@ -117,7 +121,7 @@ class ServeConfig:
     speculation: "bool | None" = None
     draft_len: int = 0
     draft_source: str = "self"
-    # observability (repro.obs): request/kernel trace spans + TTFT/TPOT
+    # observability (repro.obs): request/phase trace spans + TTFT/TPOT
     # histograms + per-step wall times in the ledger.  None = cfg.obs;
     # trace_capacity 0 = cfg.obs_trace_capacity; metrics_retention None =
     # cfg.metrics_retention (ledger rows kept; 0 = unbounded).
@@ -140,6 +144,10 @@ def _pool_copy(pool, src, dst):
 def _pool_copy_stacked(pool, src, dst):
     """Same for stacked (S, nb, bs, ...) superblock pools."""
     return pool.at[:, dst].set(pool[:, src])
+
+
+# `sample_token` reads one drawn int32 id back per token above temperature 0
+DRAWN_ID_BYTES = 4
 
 
 def sample_token(serve: ServeConfig, rid: int, token_idx: int,
@@ -230,7 +238,7 @@ class ServingEngine:
             obs_on,
             trace_capacity=serve.trace_capacity or cfg.obs_trace_capacity)
         annotate_serving_tracks(self.obs.trace, serve.slots)
-        self._kv_lane_calls = 0
+        self._d2h = 0            # device -> host bytes of the current step
 
         self.scheduler = ChunkedPrefillScheduler(
             self.kv, slots=serve.slots, chunk=chunk, prefix=self.prefix,
@@ -357,6 +365,8 @@ class ServingEngine:
 
     # ------------------------------------------------------------ engine
     def _sample(self, logits_row, req: Request) -> int:
+        if self.serve.temperature > 0.0:
+            self._d2h += DRAWN_ID_BYTES
         return sample_token(self.serve, req.rid, len(req.produced), logits_row)
 
     # ---------------------------------------------------------- drafting
@@ -475,63 +485,36 @@ class ServingEngine:
                 # the lane's refs just dropped: the block cap can now bite
                 self.prefix.enforce_cap()
 
-    # ------------------------------------------------------ trace helpers
-    # Modeled kernel lanes are emitted for every KV_LANE_STRIDE-th batched
-    # call, not every call: they replay a deterministic chunk schedule, so
-    # consecutive decode steps produce near-identical lanes, and emitting
-    # all of them dominates the telemetry cost (the <5% overhead budget in
-    # benchmarks/run.py:bench_serving_observability_overhead).
-    KV_LANE_STRIDE = 8
+    def _live_blocks(self) -> np.ndarray:
+        """(slots, groups): the physical blocks each lane maps in each
+        layer group (`blocks_for`, counted for every lane at once) — what
+        the streaming kernel reads for that lane."""
+        mb = np.arange(self.kv.cfg.max_blocks_per_seq)
+        return np.stack(
+            [np.count_nonzero((g.tables != 0) & (mb < g.num_mapped[:, None]),
+                              axis=1) for g in self.kv.groups], axis=1)
 
-    def _kv_lane_events(self, t0: float, t1: float, lanes) -> None:
-        """Modeled DMA/compute kernel lanes for one paged-attention call:
-        the chunk-issue schedule replayed over each participant's live
-        blocks, stretched into the measured call window (cat="modeled" —
-        see `kernels.paged_attention.paged_lane_events`).  Sampled every
-        KV_LANE_STRIDE-th call."""
-        self._kv_lane_calls += 1
-        if (self._kv_lane_calls - 1) % self.KV_LANE_STRIDE:
-            return
-        g0 = self.kv.groups[0]
-        counts = [len(g0.blocks_for(l)) if l in lanes else 0
-                  for l in range(self.serve.slots)]
-        paged_lane_events(
-            self.obs.trace, counts, self.kv.cfg.max_blocks_per_seq,
-            block_rows=self.block_size, itemsize=self.cfg.jdtype.itemsize,
-            block_bytes=self.block_size * sum(self._group_token_bytes),
-            t0_us=t0, dur_us=t1 - t0, pid=PID_KERNEL)
-
-    def _trace_prefill(self, w, req, t0: float) -> None:
-        """Prefill-chunk span on the lane's track + modeled lanes for the
-        chunk's dense weight-streaming matmul (the GPP GeMM schedule)."""
-        t1 = self.obs.now_us()
-        self.obs.trace.complete(
-            "prefill_chunk", t0, t1 - t0, pid=PID_SERVING,
-            tid=TID_LANE0 + w.lane, cat="phase",
-            args={"rid": req.rid, "tokens": len(w.tokens),
-                  "real_tokens": w.real_tokens, "start_pos": w.start_pos,
-                  "final": w.final})
-        matmul_lane_events(
-            self.obs.trace, len(w.tokens), self.cfg.d_model,
-            self.cfg.d_model, itemsize=self.cfg.jdtype.itemsize,
-            t0_us=t0, dur_us=t1 - t0, pid=PID_KERNEL)
-
-    def _trace_batched(self, name: str, lanes, t0: float,
-                       tokens: int) -> None:
-        """Decode/verify span on the engine track + modeled KV-ring lanes
-        for the batched paged-attention read."""
-        t1 = self.obs.now_us()
-        self.obs.trace.complete(
-            name, t0, t1 - t0, pid=PID_SERVING, tid=TID_ENGINE, cat="phase",
-            args={"lanes": len(lanes), "tokens": tokens})
-        self._kv_lane_events(t0, t1, lanes)
+    def _fetch(self, x) -> np.ndarray:
+        """Read `x` back to the host as float32, counting its bytes into
+        the step's `d2h_bytes`."""
+        self._d2h += x.nbytes
+        return np.asarray(x, np.float32)
 
     def step(self) -> bool:
         """One engine step: at most one prefill chunk + one batched decode
-        call over every decode-phase lane."""
+        call over every decode-phase lane.  The step runs inside a
+        `serve.step` profiler span numbered like its ledger row, with one
+        child span per stage (`obs.trace.phase`)."""
+        with jax.profiler.StepTraceAnnotation("serve.step",
+                                              step_num=self.metrics.steps):
+            return self._step()
+
+    def _step(self) -> bool:
         obs = self.obs
+        trace = obs.trace
         step_t0 = obs.now_us() if obs.enabled else 0.0
-        plan = self.scheduler.schedule()
+        with phase(trace, "serve.schedule"):
+            plan = self.scheduler.schedule()
         if plan is None:
             if self.scheduler.pending:
                 raise RuntimeError(
@@ -541,200 +524,219 @@ class ServingEngine:
             return False
         # copy-on-write forks queued by admission: copy pool rows before
         # any write this step
-        self._apply_pending_copies()
+        if self.kv.pending_copies:
+            with phase(trace, "serve.copy"):
+                self._apply_pending_copies()
         prefill_tokens = decode_tokens = 0
         verify_tokens = drafted_tokens = accepted_tokens = 0
         read_tokens = 0
-        # per-call attention-read accounting: the gather path materializes
-        # every participant's full (MB*bs) logical sequence in HBM; the
-        # streaming kernel only moves each participant's LIVE blocks through
-        # VMEM (unmapped/released entries re-read the hot null block).
-        attn_bytes_gather = attn_bytes_stream = 0
-        mb_rows = self.kv.cfg.max_blocks_per_seq * self.block_size
-
-        def _stream_bytes(lane: int) -> int:
-            return sum(
-                len(g.blocks_for(lane)) * self.block_size * gb
-                for g, gb in zip(self.kv.groups, self._group_token_bytes))
+        programs = 0            # model programs launched: weight streams
+        gathered = 0            # lanes whose logical sequence a gather reads
+        live = []               # live-block counts of each call's lanes
+        self._d2h = 0
 
         if plan.prefill:
             w = plan.prefill
             req = self.scheduler.request_at(w.lane)
-            # shared blocks arrive via the tables READ-ONLY; the write span
-            # must be exclusively owned (fork_block upholds this at
-            # admission — assert it before every write)
-            self.kv.assert_writable(w.lane, w.start_pos,
-                                    w.start_pos + len(w.tokens))
-            t0 = obs.now_us() if obs.enabled else 0.0
-            logits, self.caches = self._prefill(
-                self.params, self.caches,
-                jnp.asarray(w.tokens[None]),
-                self._tables_jnp(w.lane),
-                w.start_pos, w.last_idx)
-            if obs.enabled:
-                self._trace_prefill(w, req, t0)
+            lane_tid = TID_LANE0 + w.lane
+            with phase(trace, "serve.prefill.launch", tid=lane_tid,
+                       rid=req.rid, rows=len(w.tokens)):
+                # shared blocks arrive via the tables READ-ONLY; the write
+                # span must be exclusively owned (fork_block upholds this
+                # at admission — assert it before every write)
+                self.kv.assert_writable(w.lane, w.start_pos,
+                                        w.start_pos + len(w.tokens))
+                inputs = (jnp.asarray(w.tokens[None]),
+                          self._tables_jnp(w.lane))
+                with phase(trace, "serve.prefill.dispatch", tid=lane_tid):
+                    logits, self.caches = self._prefill(
+                        self.params, self.caches, *inputs,
+                        w.start_pos, w.last_idx)
+                live.append(self._live_blocks()[w.lane])
+            programs += 1
+            gathered += 1
             prefill_tokens = len(w.tokens)
             read_tokens += w.start_pos + len(w.tokens)
-            attn_bytes_gather += mb_rows * self._kv_token_bytes
-            attn_bytes_stream += _stream_bytes(w.lane)
-            if self._reclaims and w.real_tokens:
-                self.kv.release_expired(
-                    w.lane, w.start_pos + w.real_tokens - 1)
             if w.final:
-                tok = self._sample(logits[0], req)
-                req.produced.append(tok)
-                obs.requests.on_first_token(req.rid)
-                # the lane's full context KV is now written: publish it for
-                # sharing while the lane keeps decoding
-                self._prefix_insert(w.lane, req.context)
-                self.scheduler.to_decode(w.lane)
-                self._maybe_finish(w.lane, tok)
+                with phase(trace, "serve.prefill.fetch", tid=lane_tid):
+                    first = self._fetch(logits[0])
+            if w.final or self._reclaims:
+                with phase(trace, "serve.sample"):
+                    if self._reclaims and w.real_tokens:
+                        self.kv.release_expired(
+                            w.lane, w.start_pos + w.real_tokens - 1)
+                    if w.final:
+                        tok = self._sample(first, req)
+                        req.produced.append(tok)
+                        obs.requests.on_first_token(req.rid)
+                        # the lane's full context KV is now written: publish
+                        # it for sharing while the lane keeps decoding
+                        self._prefix_insert(w.lane, req.context)
+                        self.scheduler.to_decode(w.lane)
+                        self._maybe_finish(w.lane, tok)
 
         if plan.decode_lanes:
             slots = self.serve.slots
-            toks = np.zeros((slots, 1), np.int32)
-            positions = np.zeros((slots,), np.int32)
-            active = np.zeros((slots,), bool)
-            for lane in plan.decode_lanes:
-                req = self.scheduler.request_at(lane)
-                toks[lane, 0] = req.produced[-1]
-                positions[lane] = req.decode_pos
-                active[lane] = True
-                read_tokens += req.decode_pos + 1
-                self.kv.assert_writable(lane, req.decode_pos,
-                                        req.decode_pos + 1)
-            t0 = obs.now_us() if obs.enabled else 0.0
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(toks),
-                self._tables_jnp(), jnp.asarray(positions),
-                jnp.asarray(active))
-            attn_bytes_gather += slots * mb_rows * self._kv_token_bytes
-            attn_bytes_stream += sum(_stream_bytes(l) for l in range(slots))
-            logits_np = np.asarray(logits, np.float32)
-            if obs.enabled:
-                self._trace_batched("decode", plan.decode_lanes, t0,
-                                    len(plan.decode_lanes))
-            for lane in plan.decode_lanes:
-                req = self.scheduler.request_at(lane)
-                req.decode_pos += 1
-                tok = self._sample(logits_np[lane, 0], req)
-                req.produced.append(tok)
-                if self._reclaims:
-                    self.kv.release_expired(lane, req.decode_pos)
-                self._maybe_finish(lane, tok)
+            with phase(trace, "serve.decode.launch",
+                       lanes=len(plan.decode_lanes)):
+                toks = np.zeros((slots, 1), np.int32)
+                positions = np.zeros((slots,), np.int32)
+                active = np.zeros((slots,), bool)
+                for lane in plan.decode_lanes:
+                    req = self.scheduler.request_at(lane)
+                    toks[lane, 0] = req.produced[-1]
+                    positions[lane] = req.decode_pos
+                    active[lane] = True
+                    read_tokens += req.decode_pos + 1
+                    self.kv.assert_writable(lane, req.decode_pos,
+                                            req.decode_pos + 1)
+                inputs = (jnp.asarray(toks), self._tables_jnp(),
+                          jnp.asarray(positions), jnp.asarray(active))
+                with phase(trace, "serve.decode.dispatch"):
+                    logits, self.caches = self._decode(
+                        self.params, self.caches, *inputs)
+                live.append(self._live_blocks())
+            programs += 1
+            gathered += slots
+            with phase(trace, "serve.decode.fetch"):
+                logits_np = self._fetch(logits)
+            with phase(trace, "serve.sample"):
+                for lane in plan.decode_lanes:
+                    req = self.scheduler.request_at(lane)
+                    req.decode_pos += 1
+                    tok = self._sample(logits_np[lane, 0], req)
+                    req.produced.append(tok)
+                    if self._reclaims:
+                        self.kv.release_expired(lane, req.decode_pos)
+                    self._maybe_finish(lane, tok)
             decode_tokens = len(plan.decode_lanes)
 
         if plan.verify:
             v = plan.verify
             slots = self.serve.slots
             S = self.draft_len + 1
-            toks = np.zeros((slots, S), np.int32)
-            positions = np.zeros((slots,), np.int32)
-            active = np.zeros((slots,), bool)
-            nvalid = np.zeros((slots,), np.int32)
-            for lane, draft in zip(v.lanes, v.drafts):
-                req = self.scheduler.request_at(lane)
-                toks[lane, 0] = req.produced[-1]
-                toks[lane, 1 : 1 + len(draft)] = draft
-                positions[lane] = req.decode_pos
-                active[lane] = True
-                nvalid[lane] = 1 + len(draft)
-                read_tokens += req.decode_pos + 1 + len(draft)
-                # the whole write span [decode_pos, decode_pos+1+len(draft))
-                # must be exclusively owned: shared prefix blocks all sit
-                # below decode_pos (tail forked at admission) and draft
-                # blocks were freshly ensured — assert, never mutate shares
-                self.kv.assert_writable(lane, req.decode_pos,
-                                        req.decode_pos + 1 + len(draft))
-            t0 = obs.now_us() if obs.enabled else 0.0
-            logits, self.caches = self._verify(
-                self.params, self.caches, jnp.asarray(toks),
-                self._tables_jnp(), jnp.asarray(positions),
-                jnp.asarray(active), jnp.asarray(nvalid))
-            attn_bytes_gather += slots * mb_rows * self._kv_token_bytes
-            attn_bytes_stream += sum(_stream_bytes(l) for l in range(slots))
-            logits_np = np.asarray(logits, np.float32)
-            if obs.enabled:
-                self._trace_batched("verify", v.lanes, t0,
-                                    int(np.sum(nvalid)))
-            for lane, draft in zip(v.lanes, v.drafts):
-                req = self.scheduler.request_at(lane)
-                nd = len(draft)
-                drafted_tokens += nd
-                verify_tokens += nd + 1
-                # greedy-exact acceptance: every emitted token is sampled
-                # from the TARGET logits at its logical token index (the
-                # same key plain decode would use), so the stream is
-                # token-for-token identical with speculation off; draft
-                # d_{i+1} survives only if it EQUALS the sampled token
-                tok = -1
-                for i in range(nd + 1):
-                    tok = self._sample(logits_np[lane, i], req)
-                    req.decode_pos += 1
-                    req.produced.append(tok)
-                    done = req.remaining <= 0 or (
-                        self.serve.eos_token is not None
-                        and tok == self.serve.eos_token)
-                    matched = i < nd and tok == draft[i]
-                    if matched:
-                        accepted_tokens += 1
-                    if done or not matched:
-                        break
-                # rollback: drop table entries mapped past the accepted
-                # point (blocks ensured for rejected drafts go back to the
-                # pool); stale rows inside kept blocks are masked/overwritten
-                self.kv.truncate_blocks(
-                    lane, -(-req.decode_pos // self.block_size))
-                if self._reclaims:
-                    self.kv.release_expired(lane, req.decode_pos)
-                self._maybe_finish(lane, tok)
+            with phase(trace, "serve.verify.launch", lanes=len(v.lanes)):
+                toks = np.zeros((slots, S), np.int32)
+                positions = np.zeros((slots,), np.int32)
+                active = np.zeros((slots,), bool)
+                nvalid = np.zeros((slots,), np.int32)
+                for lane, draft in zip(v.lanes, v.drafts):
+                    req = self.scheduler.request_at(lane)
+                    toks[lane, 0] = req.produced[-1]
+                    toks[lane, 1 : 1 + len(draft)] = draft
+                    positions[lane] = req.decode_pos
+                    active[lane] = True
+                    nvalid[lane] = 1 + len(draft)
+                    read_tokens += req.decode_pos + 1 + len(draft)
+                    # the whole write span [decode_pos, decode_pos + 1 +
+                    # len(draft)) must be exclusively owned: shared prefix
+                    # blocks all sit below decode_pos (tail forked at
+                    # admission) and draft blocks were freshly ensured —
+                    # assert, never mutate shares
+                    self.kv.assert_writable(lane, req.decode_pos,
+                                            req.decode_pos + 1 + len(draft))
+                inputs = (jnp.asarray(toks), self._tables_jnp(),
+                          jnp.asarray(positions), jnp.asarray(active),
+                          jnp.asarray(nvalid))
+                with phase(trace, "serve.verify.dispatch"):
+                    logits, self.caches = self._verify(
+                        self.params, self.caches, *inputs)
+                live.append(self._live_blocks())
+            programs += 1
+            gathered += slots
+            with phase(trace, "serve.verify.fetch"):
+                logits_np = self._fetch(logits)
+            with phase(trace, "serve.sample"):
+                for lane, draft in zip(v.lanes, v.drafts):
+                    req = self.scheduler.request_at(lane)
+                    nd = len(draft)
+                    drafted_tokens += nd
+                    verify_tokens += nd + 1
+                    # greedy-exact acceptance: every emitted token is sampled
+                    # from the TARGET logits at its logical token index (the
+                    # same key plain decode would use), so the stream is
+                    # token-for-token identical with speculation off; draft
+                    # d_{i+1} survives only if it EQUALS the sampled token
+                    tok = -1
+                    for i in range(nd + 1):
+                        tok = self._sample(logits_np[lane, i], req)
+                        req.decode_pos += 1
+                        req.produced.append(tok)
+                        done = req.remaining <= 0 or (
+                            self.serve.eos_token is not None
+                            and tok == self.serve.eos_token)
+                        matched = i < nd and tok == draft[i]
+                        if matched:
+                            accepted_tokens += 1
+                        if done or not matched:
+                            break
+                    # rollback: drop table entries mapped past the accepted
+                    # point (blocks ensured for rejected drafts go back to
+                    # the pool); stale rows inside kept blocks are
+                    # masked/overwritten
+                    self.kv.truncate_blocks(
+                        lane, -(-req.decode_pos // self.block_size))
+                    if self._reclaims:
+                        self.kv.release_expired(lane, req.decode_pos)
+                    self._maybe_finish(lane, tok)
 
         tokens = prefill_tokens + decode_tokens + verify_tokens
-        # one typed ledger row per step (schema: obs.ledger.STEP_SCHEMA).
-        # The ledger derives hbm_bytes = param_bytes + kv_write_bytes +
-        # kv_read_bytes — the same projection the engine used to hand-build:
-        # weights stream once per step, every processed token writes its KV,
-        # reads cover each participant's live prefix.  attn_bytes_gather is
-        # the bytes `_paged_gather` would MATERIALIZE (every participant's
-        # full MB*bs logical sequence per layer); attn_bytes_stream is what
-        # the Pallas kernel DMAs (each participant's LIVE blocks per group).
-        row = self.metrics.record(
-            tokens=tokens,
-            prefill_tokens=prefill_tokens,
-            # non-pad prompt tokens in the chunk (<= prefill_tokens; the
-            # padded count is the flatness/traffic quantity)
-            prefill_real_tokens=(plan.prefill.real_tokens
-                                 if plan.prefill else 0),
-            decode_tokens=decode_tokens,
-            verify_tokens=verify_tokens,
-            drafted_tokens=drafted_tokens,
-            accepted_tokens=accepted_tokens,
-            blocks_in_use=self.kv.blocks_in_use,
-            free_blocks=self.kv.num_free,
-            queue_depth=self.scheduler.queue_depth,
-            preempted=len(plan.preempted),
-            prefix_hit_tokens=plan.prefix_hit_tokens,
-            blocks_shared=self.kv.blocks_shared,
-            param_bytes=self._param_bytes,
-            kv_write_bytes=tokens * self._kv_token_bytes,
-            kv_read_bytes=read_tokens * self._kv_token_bytes,
-            # KV the radix index served this step: re-prefill bytes that
-            # never crossed HBM
-            prefix_saved_bytes=plan.prefix_hit_tokens * self._kv_token_bytes,
-            attn_bytes_gather=attn_bytes_gather,
-            attn_bytes_stream=attn_bytes_stream,
-            step_wall_us=(obs.now_us() - step_t0) if obs.enabled else 0.0,
-        )
-        if obs.enabled:
-            obs.trace.complete(
-                "step", step_t0, row["step_wall_us"], pid=PID_SERVING,
-                tid=TID_ENGINE, cat="step",
-                args={"step": row["step"], "tokens": tokens,
-                      "hbm_bytes": row["hbm_bytes"]})
-            obs.trace.counter(
-                "hbm bytes/step",
-                {"total": row["hbm_bytes"], "stream": attn_bytes_stream},
-                ts_us=step_t0, pid=PID_SERVING)
+        with phase(trace, "serve.account"):
+            # one typed ledger row per step (schema: obs.ledger.STEP_SCHEMA).
+            # The ledger derives hbm_bytes = param_bytes + kv_write_bytes +
+            # kv_read_bytes: weights stream once per model program launched,
+            # every processed token writes its KV, reads cover each
+            # participant's live prefix.  attn_bytes_gather is the bytes
+            # `_paged_gather` would MATERIALIZE (every participant's full
+            # MB*bs logical sequence per layer); attn_bytes_stream is what
+            # the Pallas kernel DMAs (each participant's LIVE blocks per
+            # group, counted when its call was launched).
+            group_bytes = np.asarray(self._group_token_bytes, np.int64)
+            mb_rows = self.kv.cfg.max_blocks_per_seq * self.block_size
+            row = self.metrics.record(
+                tokens=tokens,
+                prefill_tokens=prefill_tokens,
+                # non-pad prompt tokens in the chunk (<= prefill_tokens; the
+                # padded count is the flatness/traffic quantity)
+                prefill_real_tokens=(plan.prefill.real_tokens
+                                     if plan.prefill else 0),
+                decode_tokens=decode_tokens,
+                verify_tokens=verify_tokens,
+                drafted_tokens=drafted_tokens,
+                accepted_tokens=accepted_tokens,
+                blocks_in_use=self.kv.blocks_in_use,
+                free_blocks=self.kv.num_free,
+                queue_depth=self.scheduler.queue_depth,
+                preempted=len(plan.preempted),
+                prefix_hit_tokens=plan.prefix_hit_tokens,
+                blocks_shared=self.kv.blocks_shared,
+                param_bytes=programs * self._param_bytes,
+                kv_write_bytes=tokens * self._kv_token_bytes,
+                kv_read_bytes=read_tokens * self._kv_token_bytes,
+                # KV the radix index served this step: re-prefill bytes that
+                # never crossed HBM
+                prefix_saved_bytes=(plan.prefix_hit_tokens
+                                    * self._kv_token_bytes),
+                # each accepted draft token is one decode call's weight
+                # stream avoided
+                spec_saved_bytes=accepted_tokens * self._param_bytes,
+                attn_bytes_gather=gathered * mb_rows * self._kv_token_bytes,
+                attn_bytes_stream=self.block_size * int(
+                    sum((n * group_bytes).sum() for n in live)),
+                d2h_bytes=self._d2h,
+                step_wall_us=(obs.now_us() - step_t0) if obs.enabled else 0.0,
+            )
+            if obs.enabled:
+                trace.complete(
+                    "step", step_t0, row["step_wall_us"], pid=PID_SERVING,
+                    tid=TID_ENGINE, cat="step",
+                    args={"step": row["step"], "tokens": tokens,
+                          "hbm_bytes": row["hbm_bytes"]})
+                trace.counter(
+                    "hbm bytes/step",
+                    {"total": row["hbm_bytes"],
+                     "stream": row["attn_bytes_stream"]},
+                    ts_us=step_t0, pid=PID_SERVING)
         return True
 
     def defragment(self) -> None:

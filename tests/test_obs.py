@@ -17,9 +17,8 @@ from repro.obs import make_telemetry
 from repro.obs.ledger import STEP_SCHEMA, BandwidthLedger, step_row
 from repro.obs.metrics import (Histogram, MetricsRegistry, RequestTracker,
                                percentile)
-from repro.obs.trace import (NULL_TRACE, PID_KERNEL, PID_REQUESTS,
-                             PID_SERVING, TID_COMPUTE, TID_DMA,
-                             TraceRecorder)
+from repro.obs.trace import (NULL_TRACE, PID_REQUESTS, PID_SERVING,
+                             TraceRecorder, phase)
 from repro.serving import DenseServingEngine, ServeConfig, ServingEngine
 
 pytestmark = pytest.mark.tier1
@@ -147,6 +146,21 @@ class TestTraceRecorder:
         (ev,) = tr.events
         assert ev["ph"] == "X" and ev["dur"] > 0 and ev["args"]["k"] == 1
 
+    def test_phase_records_only_with_telemetry(self):
+        """`phase` is a profiler annotation either way; the recorder gets
+        the same span as an X event only when telemetry is on."""
+        with phase(NULL_TRACE, "serve.schedule", lanes=2):
+            pass
+        assert len(NULL_TRACE) == 0
+        tr = TraceRecorder(clock=self._fake_clock())
+        with phase(tr, "serve.decode.launch", tid=11, lanes=2):
+            pass
+        (ev,) = tr.events
+        assert (ev["name"], ev["ph"], ev["cat"]) == (
+            "serve.decode.launch", "X", "phase")
+        assert (ev["pid"], ev["tid"]) == (PID_SERVING, 11)
+        assert ev["args"] == {"lanes": 2} and ev["dur"] > 0
+
     def test_null_trace_is_inert(self):
         assert not NULL_TRACE.enabled
         assert len(NULL_TRACE) == 0
@@ -166,8 +180,11 @@ class TestLedger:
         assert set(row) == set(STEP_SCHEMA)
         assert row["hbm_bytes"] == 200          # params + writes + reads
         assert row["acceptance_rate"] == pytest.approx(0.75)
-        assert row["spec_saved_bytes"] == 6 * 100
+        # param_bytes counts every program's weight stream, so the weight
+        # streams accepted drafts saved are the engine's to pass
+        assert row["spec_saved_bytes"] == 0
         assert row["prefill_tokens"] == 0       # unset fields zero-fill
+        assert row["d2h_bytes"] == 0
         with pytest.raises(ValueError):
             step_row(not_a_field=1)
 
@@ -277,17 +294,28 @@ class TestEngineIntegration:
                                       + m["kv_read_bytes"])
 
     def test_trace_covers_requests_steps_and_kernel_lanes(self, qwen):
+        """Requests, steps and the engine's phases are recorded; the
+        modelled kernel lanes are gone (no track but serving and
+        requests)."""
         cfg, params = qwen
         streams, eng = _run(ServingEngine, cfg, params, obs=True)
         evs = eng.obs.trace.events
-        assert any(e["ph"] == "X" and e["pid"] == PID_SERVING
-                   and e["name"] == "step" for e in evs)
+        steps = [e for e in evs if e["ph"] == "X" and e["name"] == "step"]
+        assert len(steps) == len(eng.metrics)
+        assert all(e["pid"] == PID_SERVING for e in steps)
         begins = [e for e in evs if e["ph"] == "b" and e["pid"] == PID_REQUESTS]
         ends = [e for e in evs if e["ph"] == "e" and e["pid"] == PID_REQUESTS]
         assert len(begins) == len(streams) and len(ends) == len(streams)
-        kernel_tids = {e["tid"] for e in evs
-                       if e["pid"] == PID_KERNEL and e["ph"] == "X"}
-        assert {TID_DMA, TID_COMPUTE} <= kernel_tids   # both modeled lanes
+        phases = {e["name"] for e in evs if e.get("cat") == "phase"}
+        assert phases == {"serve.schedule", "serve.prefill.launch",
+                          "serve.prefill.dispatch", "serve.prefill.fetch",
+                          "serve.decode.launch", "serve.decode.dispatch",
+                          "serve.decode.fetch", "serve.sample",
+                          "serve.account"}
+        launch = next(e for e in evs if e["name"] == "serve.prefill.launch")
+        assert set(launch["args"]) == {"rid", "rows"}
+        assert {e["pid"] for e in evs} == {PID_SERVING, PID_REQUESTS}
+        assert not any(e.get("cat") == "modeled" for e in evs)
         # trace JSON is loadable end-to-end
         doc = json.loads(json.dumps(eng.obs.trace.to_chrome()))
         assert doc["traceEvents"]
@@ -312,3 +340,159 @@ class TestEngineIntegration:
         assert not t_off.enabled and t_off.trace is NULL_TRACE
         with pytest.raises(RuntimeError):
             t_off.write_metrics("/dev/null")
+
+
+# ------------------------------------------------ ledger counters, per step
+def _programs(row) -> int:
+    """Model programs a paged-engine step launched, from its row."""
+    return sum(bool(row[k]) for k in ("prefill_tokens", "decode_tokens",
+                                      "verify_tokens"))
+
+
+class TestStepCounters:
+    def test_param_bytes_count_each_program(self, qwen):
+        """A step that runs a prefill chunk and a decode call streams the
+        weights twice, and the ledger charges them twice."""
+        cfg, params = qwen
+        _, eng = _run(ServingEngine, cfg, params, obs=False)
+        rows = list(eng.metrics)
+        assert any(_programs(r) == 2 for r in rows)
+        for r in rows:
+            assert r["param_bytes"] == _programs(r) * eng._param_bytes
+
+    def test_spec_saved_bytes_one_stream_per_accepted_token(self, qwen):
+        cfg, params = qwen
+        eng = ServingEngine(cfg, params, ServeConfig(
+            slots=2, max_len=96, speculation=True, draft_len=3))
+        for p in ([7, 9] * 8, [3, 4, 5] * 5):
+            eng.submit(p, max_new_tokens=12)
+        eng.run()
+        rows = list(eng.metrics)
+        assert sum(r["accepted_tokens"] for r in rows) > 0
+        for r in rows:
+            assert r["param_bytes"] == _programs(r) * eng._param_bytes
+            assert r["spec_saved_bytes"] == (r["accepted_tokens"]
+                                             * eng._param_bytes)
+
+    def test_dense_param_bytes_count_each_program(self, qwen):
+        cfg, params = qwen
+        _, eng = _run(DenseServingEngine, cfg, params, obs=False)
+        counts = [r["param_bytes"] // eng._param_bytes for r in eng.metrics]
+        assert all(r["param_bytes"] == n * eng._param_bytes
+                   for r, n in zip(eng.metrics, counts))
+        # step 0 prefills both lanes and decodes them: at least 3 programs
+        assert counts[0] >= 3 and min(counts) >= 1
+
+    @pytest.mark.parametrize("engine_cls", (ServingEngine,
+                                            DenseServingEngine))
+    def test_d2h_bytes_count_the_logits_read_back(self, qwen, engine_cls):
+        """Greedy steps read back float32 logits: one row per request's
+        first token, plus every lane of each decode call (paged), or one
+        row per sampled token (dense)."""
+        cfg, params = qwen
+        streams, eng = _run(engine_cls, cfg, params, obs=False)
+        row_bytes = cfg.vocab_size * 4
+        rows = list(eng.metrics)
+        if engine_cls is ServingEngine:
+            decode_calls = sum(bool(r["decode_tokens"]) for r in rows)
+            want = (len(streams) + 2 * decode_calls) * row_bytes
+        else:
+            want = sum(len(s) for s in streams) * row_bytes
+        assert sum(r["d2h_bytes"] for r in rows) == want
+        assert all(r["d2h_bytes"] % row_bytes == 0 for r in rows)
+
+    def test_temperature_counts_the_drawn_ids(self, qwen):
+        cfg, params = qwen
+        _, greedy = _run(ServingEngine, cfg, params, obs=False)
+        streams, hot = _run(ServingEngine, cfg, params, obs=False,
+                            temperature=0.8)
+        drawn = sum(len(s) for s in streams)
+        assert hot.metrics.total("d2h_bytes") == (
+            greedy.metrics.total("d2h_bytes") + 4 * drawn)
+
+
+    def test_live_blocks_match_blocks_for(self):
+        """The vectorized live-block count behind `attn_bytes_stream` equals
+        the loop over `blocks_for` it replaced, through window releases,
+        prefix shares and finished lanes."""
+        cfg = registry.get_config("gemma3-12b", smoke=True)
+        eng = ServingEngine(cfg, tf.init_params(cfg, jax.random.PRNGKey(0)),
+                            ServeConfig(slots=2, max_len=96, block_size=8,
+                                        prefill_chunk=16, prefix_cache=True))
+        system = list(range(1, 30))
+        for tail, n in (([40, 41], 5), ([50], 6), ([40, 41, 7], 4)):
+            eng.submit(system + tail, max_new_tokens=n)
+        while eng.pending:
+            eng.step()
+            want = [[len(g.blocks_for(lane)) for g in eng.kv.groups]
+                    for lane in range(2)]
+            assert eng._live_blocks().tolist() == want
+        assert eng.metrics.total("prefix_hit_tokens") > 0
+
+
+# ------------------------------------------- spans on the profiler's clock
+STEP_ORDER = ("serve.schedule", "serve.copy", "serve.prefill.launch",
+              "serve.prefill.dispatch", "serve.prefill.fetch",
+              "serve.sample", "serve.decode.launch", "serve.decode.dispatch",
+              "serve.decode.fetch", "serve.sample", "serve.verify.launch",
+              "serve.verify.dispatch", "serve.verify.fetch", "serve.sample",
+              "serve.account")
+
+
+def _serve_spans(logdir) -> list:
+    """(name, start_ns, end_ns, stats) of every `serve.*` host event in the
+    profile written under `logdir`, by start."""
+    (path,) = logdir.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    out = []
+    for plane in pd.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("serve."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+class TestProfilerSpans:
+    def test_steps_and_stages_on_the_profiler_clock(self, qwen, tmp_path):
+        cfg, params = qwen
+        off, eng_off = _run(ServingEngine, cfg, params, obs=False)
+        with jax.profiler.trace(str(tmp_path)):
+            on, eng_on = _run(ServingEngine, cfg, params, obs=False)
+        # the profiler changes neither the tokens nor the ledger
+        assert on == off
+        assert list(eng_on.metrics) == list(eng_off.metrics)
+
+        spans = _serve_spans(tmp_path)
+        steps = [e for e in spans if e[0] == "serve.step"]
+        rows = list(eng_on.metrics)
+        assert [e[3]["step_num"] for e in steps] == [r["step"] for r in rows]
+        children = [e for e in spans if e[0] != "serve.step"]
+        for (_, s0, s1, _), row in zip(steps, rows):
+            inner = [e for e in children if s0 <= e[1] and e[2] <= s1]
+            names = [e[0] for e in inner]
+            # one after another, in the order the step runs them; a
+            # dispatch lies inside its launch
+            for a, b in zip(inner, inner[1:]):
+                nested = (b[0].endswith(".dispatch")
+                          and a[0] == b[0].replace(".dispatch", ".launch"))
+                assert (b[2] <= a[2]) if nested else (a[2] <= b[1])
+            at = 0
+            for n in names:             # raises when a stage is out of order
+                at = STEP_ORDER.index(n, at) + 1
+            assert names[0] == "serve.schedule"
+            assert names[-1] == "serve.account"
+            assert ("serve.prefill.launch" in names) == bool(
+                row["prefill_tokens"])
+            assert ("serve.decode.launch" in names) == bool(
+                row["decode_tokens"])
+            assert names.count("serve.decode.fetch") == bool(
+                row["decode_tokens"])
+            if row["decode_tokens"]:
+                (launch,) = [e for e in inner
+                             if e[0] == "serve.decode.launch"]
+                assert launch[3]["lanes"] == row["decode_tokens"]
+        # every stage span lies inside a step
+        assert sum(len([e for e in children if s0 <= e[1] and e[2] <= s1])
+                   for _, s0, s1, _ in steps) == len(children)

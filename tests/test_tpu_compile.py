@@ -139,6 +139,40 @@ def test_paged_attention_mla(one_chip, B, S):
              _sds(one_chip, (B,), jnp.int32))
 
 
+# ------------------------------------------------------------ kernel names
+
+@pytest.mark.parametrize("name", ["gpp_matmul", "gpp_matmul_grouped",
+                                  "paged_attention"])
+def test_kernels_carry_their_names(one_chip, name):
+    """Each Pallas call is named, so the compiled module (and the device
+    trace, which shows its instructions) calls the kernel by that name."""
+    import re
+
+    from repro.kernels.gpp_matmul import gpp_matmul, gpp_matmul_grouped
+    from repro.kernels.paged_attention import paged_attention
+    calls = {
+        "gpp_matmul": (gpp_matmul, _sds(one_chip, (8, D)),
+                       _sds(one_chip, (D, FF))),
+        "gpp_matmul_grouped": (gpp_matmul_grouped,
+                               _sds(one_chip, (4, 8, D)),
+                               _sds(one_chip, (4, D, FF))),
+        "paged_attention": (
+            lambda q, a, b, t, p: paged_attention(
+                q, a, b, t, p, num_kv_heads=HEADS, scale=HD ** -0.5),
+            _sds(one_chip, (SLOTS, 1, HEADS, HD)),
+            _sds(one_chip, (NB, BS, HEADS, HD)),
+            _sds(one_chip, (NB, BS, HEADS, HD)),
+            _sds(one_chip, (SLOTS, MB), jnp.int32),
+            _sds(one_chip, (SLOTS,), jnp.int32)),
+    }
+    fn, *args = calls[name]
+    lowered = jax.jit(fn).lower(*args)
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    assert re.search(rf'%{name}(\.\d+)? = .*custom_call_target='
+                     r'"tpu_custom_call"', hlo)
+
+
 # ------------------------------------------------------ whole serve step
 
 def _on_chip(sharding, tree):
