@@ -296,9 +296,13 @@ def paged_attn(
     tables: (B, MB) int32 block table (0 = reserved null block);
     positions: (B,) int32 per-lane query start positions.
 
-    GQA: pools are k/v, dk = head_dim.  MLA (`mla=True`): pools are
-    c_kv/k_rope, q is already absorbed through w_uk (dk = kv_lora + rope),
-    and the return value is the latent output for the caller to up-project.
+    GQA: pools are k/v, dk = head_dim, stored lane-dense as
+    (nb, bs, KVH*hd) (`models.attention.paged_cache_specs`: XLA relaid a
+    (nb, bs, KVH, hd) pool with hd under 128 lanes out around every write
+    and read); (nb, bs, KVH, hd) pools are accepted too.  MLA
+    (`mla=True`): pools are c_kv/k_rope, q is already absorbed through w_uk
+    (dk = kv_lora + rope), and the return value is the latent output for
+    the caller to up-project.
     A k_rope pool wider than q's rope lanes (zero-padded to whole 128-lane
     tiles, `models.attention.paged_cache_specs`) gets q zero-padded to
     match, so the padding adds nothing to the logits.

@@ -37,8 +37,8 @@ inactive lane parked at position 0) read the null block and are masked.
 
 One kernel body serves the whole family:
 
-  GQA / MHA / sliding window   pool_a = k  (nb, bs, KVH, hd)
-                               pool_b = v  (nb, bs, KVH, hd)
+  GQA / MHA / sliding window   pool_a = k  (nb, bs, KVH*hd)
+                               pool_b = v  (nb, bs, KVH*hd)
   MLA (weight-absorbed MQA)    pool_a = c_kv   (nb, bs, kv_lora)
                                pool_b = k_rope (nb, bs, rope_dim)
       with q absorbed through w_uk (models/attention._mla_absorbed_q):
@@ -50,7 +50,13 @@ Queries arrive pre-scaled and pre-transposed as (B, KVH, rep*S, dk) so the
 kernel body is nothing but DMA waits, two batched dot_generals (batch =
 KV head, leading), and the online-softmax update — no in-kernel transposes
 or reshapes: a (bs, KVH*hd) KV tile splits into heads by static lane
-slices.  Decode is S=1 with per-lane positions; a prefill chunk is B=1,
+slices.  The model's GQA pools are stored in that lane-dense layout
+(`models.attention.paged_cache_specs`): stored as (nb, bs, KVH, hd), a hd
+under 128 lanes (or KVH x hd short of a whole tile) made XLA keep the pool
+block-minor and relay all of it out around every write and every call.
+(nb, bs, KVH, hd) pools are still accepted and flattened here.
+
+Decode is S=1 with per-lane positions; a prefill chunk is B=1,
 S=chunk with ANY start position (prefix-cache hits resume prefill
 mid-block — the visibility predicate and per-(row, slot) mask are
 position-exact, never block-aligned-assuming); both compile to the same
@@ -239,10 +245,12 @@ def paged_attention(
          prefill chunk: B == 1 with any (not necessarily block-aligned)
          start position.
       pool_a / pool_b: shared physical pools, leading dims (nb, bs, ...).
-         GQA: k / v with trailing (KVH, hd).  MLA: c_kv (nb, bs, kv_lora) /
-         k_rope (nb, bs, rope_dim) with `mla=True` and q already absorbed
-         through w_uk (dk = kv_lora + rope_dim); returns the latent output
-         (B, S, H, kv_lora) for the caller to up-project through w_uv.
+         GQA: k / v of (nb, bs, KVH*hd), the model's lane-dense layout, or
+         (nb, bs, KVH, hd), flattened to it here.  MLA: c_kv
+         (nb, bs, kv_lora) / k_rope (nb, bs, rope_dim) with `mla=True` and
+         q already absorbed through w_uk (dk = kv_lora + rope_dim); returns
+         the latent output (B, S, H, kv_lora) for the caller to up-project
+         through w_uv.
       tables: (B, MB) int32 block table — entry 0 is the reserved null block
          (unmapped / inactive lanes), masked by construction.
       positions: (B,) int32 — each lane's query start position (decode: the
@@ -274,7 +282,8 @@ def paged_attention(
         raise ValueError(f"{H} heads not divisible by {kvh} kv heads")
     rep = H // kvh
     # flatten trailing dims: one 3-D (nb, bs, F) layout per pool, so the ring
-    # DMA helpers see the same (rows, lanes) tiles as the matmul kernel.
+    # DMA helpers see the same (rows, lanes) tiles as the matmul kernel (a
+    # no-op for the model's pools, which are stored this way).
     pa = pool_a.reshape(nb, bs, -1)
     pb = pool_b.reshape(nb, bs, -1)
     Fa, Fb = pa.shape[-1], pb.shape[-1]
@@ -284,7 +293,7 @@ def paged_attention(
             raise ValueError(
                 f"mla q dk={dk} != kv_lora {Fa} + rope {Fb}")
     else:
-        dv = pool_b.shape[-1]
+        dv = Fb // kvh
         if Fa != kvh * dk:
             raise ValueError(
                 f"k pool trailing {pool_a.shape[2:]} does not match "

@@ -68,8 +68,10 @@ def paged_attn_ref(q, pool_a, pool_b, tables, positions, *, num_kv_heads,
         kvh = 1
     else:
         kvh = num_kv_heads
-        kseq = gather(pool_a)                     # (B, T, KVH, hd)
-        vseq = gather(pool_b)
+        # (B, T, KVH, hd) from either pool layout, (nb, bs, KVH*hd) or
+        # (nb, bs, KVH, hd)
+        kseq = gather(pool_a).reshape(B, -1, kvh, dk)
+        vseq = gather(pool_b).reshape(*kseq.shape[:3], -1)
     T = kseq.shape[1]
 
     # dense mask over the gathered sequence: key slot t holds absolute

@@ -360,9 +360,15 @@ def gqa_decode(p, c: AttnConfig, x, cache, pos):
 # ---------------------------------------------------------------------------
 #
 # Pool layouts (no per-lane batch dim; capacity shared across lanes):
-#   full/window k/v: (num_blocks, block_size, KVH, hd)
+#   full/window k/v: (num_blocks, block_size, KVH*hd)
 #   MLA            : c_kv (num_blocks, block_size, kv_lora),
 #                    k_rope (num_blocks, block_size, round_up(rope_dim, 128))
+# Every pool is lane-dense: its minor dim is a token's whole row, the
+# (bs, F) tile `paged_attention` streams.  A (..., KVH, hd) pool whose hd
+# (or KVH x hd tile) does not fill the TPU's (sublane, 128-lane) tiling made
+# XLA store it block-minor and relayout the whole pool several times per
+# layer in every step program; the flat pool is written in place.  Writes
+# flatten each token's (KVH, hd) row into the last dim.
 # The k_rope pool is zero-padded to whole 128-lane tiles: Mosaic cannot DMA
 # a block out of an HBM array whose lane dim is not tile-aligned.  Padded
 # lanes are written as zeros and meet zero query lanes, so logits are exact.
@@ -375,7 +381,9 @@ def gqa_decode(p, c: AttnConfig, x, cache, pos):
 
 
 def paged_cache_specs(c: AttnConfig, num_blocks: int, block_size: int):
-    """Pool ShapeDtypeStructs for one attention layer (shared across lanes)."""
+    """Pool ShapeDtypeStructs for one attention layer (shared across lanes):
+    GQA/MHA k and v are (num_blocks, block_size, KVH*hd), lane-dense (see
+    the layout note above)."""
     if c.is_mla:
         return {
             "c_kv": sds((num_blocks, block_size, c.kv_lora_rank), c.dtype),
@@ -383,8 +391,10 @@ def paged_cache_specs(c: AttnConfig, num_blocks: int, block_size: int):
                            round_up(c.rope_head_dim, 128)), c.dtype),
         }
     return {
-        "k": sds((num_blocks, block_size, c.num_kv_heads, c.head_dim), c.dtype),
-        "v": sds((num_blocks, block_size, c.num_kv_heads, c.head_dim), c.dtype),
+        "k": sds((num_blocks, block_size, c.num_kv_heads * c.head_dim),
+                 c.dtype),
+        "v": sds((num_blocks, block_size, c.num_kv_heads * c.head_dim),
+                 c.dtype),
     }
 
 
@@ -413,7 +423,7 @@ def _paged_write_span(pool, table_row, start_pos, vals):
     S = vals.shape[1]
     pos = start_pos + jnp.arange(S, dtype=jnp.int32)
     blk = jnp.take(table_row[0], pos // bs)
-    return pool.at[blk, pos % bs].set(vals[0])
+    return pool.at[blk, pos % bs].set(vals[0].reshape(S, *pool.shape[2:]))
 
 
 def _paged_write_token(pool, tables, positions, active, vals):
@@ -425,7 +435,7 @@ def _paged_write_token(pool, tables, positions, active, vals):
     blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)[:, 0]
     blk = jnp.where(active, blk, 0)
     off = jnp.where(active, positions % bs, 0)
-    return pool.at[blk, off].set(vals)
+    return pool.at[blk, off].set(vals.reshape(B, *pool.shape[2:]))
 
 
 def _paged_write_multi(pool, tables, positions, active, nvalid, vals):
@@ -441,7 +451,7 @@ def _paged_write_multi(pool, tables, positions, active, nvalid, vals):
     blk = jnp.take_along_axis(tables, jnp.where(valid, pos // bs, 0), axis=1)
     blk = jnp.where(valid, blk, 0)
     off = jnp.where(valid, pos % bs, 0)
-    return pool.at[blk, off].set(vals)
+    return pool.at[blk, off].set(vals.reshape(*pos.shape, *pool.shape[2:]))
 
 
 def paged_mask(positions, T: int, *, S: int = 1, window: "int | None" = None):

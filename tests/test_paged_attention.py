@@ -139,6 +139,24 @@ class TestOpParity:
             np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
                                        rtol=1e-6, atol=1e-6)
 
+    @pytest.mark.parametrize("mode", ("ref", "interpret"))
+    def test_four_dim_pools_read_as_the_lane_dense_layout(self, mode):
+        """The model stores GQA pools lane-dense, (nb, bs, KVH*hd); pools
+        built as (nb, bs, KVH, hd) are still accepted and read exactly as
+        the flat pools holding the same rows."""
+        c = _attn_cfg("gqa", "auto")
+        pools = _pools(c, 11, 8)
+        assert pools["k"].shape == pools["v"].shape == (11, 8, 2 * 16)
+        four = {k: p.reshape(11, 8, 2, 16) for k, p in pools.items()}
+        tables = jnp.asarray([[7, 2, 9, 4], [1, 5, 0, 0]], jnp.int32)
+        positions = jnp.asarray([26, 9], jnp.int32)
+        q = jax.random.normal(jax.random.PRNGKey(10), (2, 3, 4, 16))
+        kw = dict(num_kv_heads=2, scale=0.25, mode=mode)
+        flat = paged_attn(q, pools["k"], pools["v"], tables, positions, **kw)
+        got = paged_attn(q, four["k"], four["v"], tables, positions, **kw)
+        assert flat.shape == (2, 3, 4, 16)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(flat))
+
     def test_mode_validation(self):
         with pytest.raises(ValueError):
             resolve_paged_attn_mode("bogus")
@@ -184,6 +202,37 @@ class TestAttentionLevelParity:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(ref, np.float32),
                                    rtol=1e-4, atol=1e-4)
+
+
+class TestPoolWrites:
+    @pytest.mark.parametrize("write", ("span", "token", "multi"))
+    def test_token_rows_land_flattened(self, write):
+        """Each write helper stores a token's (KVH, hd) row as one lane-dense
+        pool row: the same bytes a (nb, bs, KVH, hd) pool would hold."""
+        c = _attn_cfg("gqa", "ref")
+        flat = _pools(c, 9, 8)["k"]
+        four = flat.reshape(9, 8, 2, 16)
+        tables = jnp.asarray([[3, 1, 4, 2], [5, 6, 7, 8]], jnp.int32)
+        positions = jnp.asarray([13, 21], jnp.int32)
+        active = jnp.asarray([True, True])
+        key = jax.random.PRNGKey(11)
+        if write == "span":
+            vals = jax.random.normal(key, (1, 6, 2, 16))
+            fn = lambda pool: A._paged_write_span(pool, tables[:1], 5, vals)
+        elif write == "token":
+            vals = jax.random.normal(key, (2, 2, 16))
+            fn = lambda pool: A._paged_write_token(pool, tables, positions,
+                                                   active, vals)
+        else:
+            vals = jax.random.normal(key, (2, 3, 2, 16))
+            fn = lambda pool: A._paged_write_multi(
+                pool, tables, positions, active,
+                jnp.asarray([3, 2], jnp.int32), vals)
+        got, want = fn(flat), fn(four)
+        assert got.shape == flat.shape
+        assert not np.array_equal(np.asarray(got), np.asarray(flat))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want).reshape(flat.shape))
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,8 @@ the described device's kind.
 """
 from __future__ import annotations
 
+import re
+
 import pytest
 
 import jax
@@ -179,13 +181,50 @@ def _on_chip(sharding, tree):
     return jax.tree.map(lambda s: _sds(sharding, s.shape, s.dtype), tree)
 
 
-def _full_width(sharding):
-    """qwen1.5-0.5b with both kernels forced on: (cfg, params, caches)."""
+def _full_width(sharding, cfg=QWEN):
+    """`cfg` at its published widths with both kernels forced on:
+    (cfg, params, caches)."""
     from repro.models import transformer as tf
-    cfg = QWEN.with_(dense_kernel="kernel", paged_attn_kernel="pallas")
+    cfg = cfg.with_(dense_kernel="kernel", paged_attn_kernel="pallas")
     params = _on_chip(sharding, jax.eval_shape(
         lambda: tf.init_params(cfg, jax.random.PRNGKey(0))))
     return cfg, params, _on_chip(sharding, tf.paged_cache_specs(cfg, NB, BS))
+
+
+def _compile_decode(sharding, cfg=QWEN):
+    """The full-width decode step over SLOTS lanes of MAX_LEN tokens."""
+    from repro.models import transformer as tf
+    cfg, params, caches = _full_width(sharding, cfg)
+    tables = tuple(_sds(sharding, (SLOTS, MB), jnp.int32)
+                   for _ in tf.layer_group_keys(cfg))
+    return _compile(
+        lambda p, c, tok, tb, pos, act: tf.decode_step_paged(
+            p, cfg, tok, c, tb, pos, act),
+        params, caches, _sds(sharding, (SLOTS, 1), jnp.int32), tables,
+        _sds(sharding, (SLOTS,), jnp.int32),
+        _sds(sharding, (SLOTS,), jnp.bool_))
+
+
+def _compile_prefill(sharding, cfg=QWEN):
+    """One full-width prefill chunk (the engine's planned 32 tokens)."""
+    from repro.models import transformer as tf
+    cfg, params, caches = _full_width(sharding, cfg)
+    rows = tuple(_sds(sharding, (1, MB), jnp.int32)
+                 for _ in tf.layer_group_keys(cfg))
+    scalar = _sds(sharding, (), jnp.int32)
+    return _compile(
+        lambda p, c, tok, row, start, last: tf.prefill_chunk(
+            p, cfg, tok, c, row, start, last),
+        params, caches, _sds(sharding, (1, 32), jnp.int32), rows, scalar,
+        scalar)
+
+
+@pytest.fixture(scope="module")
+def qwen_steps(one_chip):
+    """qwen1.5-0.5b's decode step and prefill chunk, compiled once for the
+    tests below."""
+    return {"decode": _compile_decode(one_chip),
+            "prefill": _compile_prefill(one_chip)}
 
 
 def _assert_fits(compiled):
@@ -195,30 +234,71 @@ def _assert_fits(compiled):
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB > 16 GiB"
 
 
-def test_decode_step_paged_fits_one_chip(one_chip):
+def test_decode_step_paged_fits_one_chip(qwen_steps):
     """The full-width qwen1.5-0.5b decode step with both kernels on: 8
     lanes x 2048 tokens of paged KV must fit one chip's HBM."""
-    from repro.models import transformer as tf
-    cfg, params, caches = _full_width(one_chip)
-    tables = tuple(_sds(one_chip, (SLOTS, MB), jnp.int32)
-                   for _ in tf.layer_group_keys(cfg))
-    _assert_fits(_compile(
-        lambda p, c, tok, tb, pos, act: tf.decode_step_paged(
-            p, cfg, tok, c, tb, pos, act),
-        params, caches, _sds(one_chip, (SLOTS, 1), jnp.int32), tables,
-        _sds(one_chip, (SLOTS,), jnp.int32),
-        _sds(one_chip, (SLOTS,), jnp.bool_)))
+    _assert_fits(qwen_steps["decode"])
 
 
-def test_prefill_chunk_fits_one_chip(one_chip):
+def test_prefill_chunk_fits_one_chip(qwen_steps):
     """One full-width prefill chunk (the engine's planned 32 tokens)."""
-    from repro.models import transformer as tf
-    cfg, params, caches = _full_width(one_chip)
-    rows = tuple(_sds(one_chip, (1, MB), jnp.int32)
-                 for _ in tf.layer_group_keys(cfg))
-    scalar = _sds(one_chip, (), jnp.int32)
-    _assert_fits(_compile(
-        lambda p, c, tok, row, start, last: tf.prefill_chunk(
-            p, cfg, tok, c, row, start, last),
-        params, caches, _sds(one_chip, (1, 32), jnp.int32), rows, scalar,
-        scalar))
+    _assert_fits(qwen_steps["prefill"])
+
+
+# ------------------------------------------------- KV pool layout on chip
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([a-z][\w\-]*)\(%([\w.\-]+)")
+_MEMORY_SPACE = re.compile(r"S\(\d+\)")
+RELAYOUT_OPS = ("copy", "copy-start", "reshape", "transpose")
+
+
+def _pool_ops(hlo: str) -> "list[tuple[str, str, str, str]]":
+    """(opcode, name, result, operand's result) of every instruction among
+    RELAYOUT_OPS whose result carries the pools' (NB, BS) dims; a tuple
+    result (`copy-start`) is read by its first element, the copy."""
+    types, found = {}, []
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, result, opcode, operand = m.groups()
+        result = result.lstrip("(").split(", ")[0]
+        types[name] = result
+        dims = re.match(r"\w+\[([\d,]*)\]", result)
+        if (opcode in RELAYOUT_OPS and dims
+                and f",{NB},{BS}," in f",{dims.group(1)},"):
+            found.append((opcode, name, result, types.get(operand, "")))
+    return found
+
+
+def _pool_relayouts(hlo: str) -> "list[str]":
+    """The pool ops that change a pool's layout: all of `_pool_ops` but a
+    copy that moves a pool between memory spaces with its layout and tiling
+    kept (the eviction of a pool staged in VMEM back to HBM)."""
+    return [f"{opcode} {name}: {result}"
+            for opcode, name, result, src in _pool_ops(hlo)
+            if not (opcode in ("copy", "copy-start")
+                    and _MEMORY_SPACE.sub("", result)
+                    == _MEMORY_SPACE.sub("", src))]
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_qwen_pools_are_never_copied(qwen_steps, step):
+    """qwen1.5-0.5b's (NB, BS, 16 x 64) pools: stored with the 64-lane head
+    as a dim of its own, XLA relaid each pool out around every write and
+    kernel call of every layer.  Stored lane-dense, the step programs copy,
+    reshape or transpose no pool at all."""
+    assert not _pool_ops(qwen_steps[step].as_text())
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_gqa_pools_keep_their_layout(one_chip, step):
+    """qwen2-7b's GQA pools (4 kv heads x 128): (4, 128) heads fill no
+    (16, 128) bf16 tile, so XLA reshaped each pool into the kernel's
+    (BS, 512) rows.  Two layers show every per-layer op."""
+    from repro.configs.qwen2_7b import CONFIG as QWEN2_7B
+    compile_step = {"decode": _compile_decode,
+                    "prefill": _compile_prefill}[step]
+    compiled = compile_step(one_chip, QWEN2_7B.with_(num_layers=2))
+    assert not _pool_relayouts(compiled.as_text())
