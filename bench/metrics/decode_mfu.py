@@ -1,6 +1,7 @@
-"""The decode step's model operations (2 per streamed parameter per active
-lane, plus attention over each lane's live context) over its device time
-at the chip's peak (device trace; counts from bench/work.py)."""
+"""The decode step's model operations (2 per activated parameter per active
+lane, plus attention over each lane's live context; counted by the
+architecture's step work, bench/arch) over its device time at the chip's
+peak (device trace)."""
 from bench import trace_reduce
 from bench.stats import on_device, traced_work
 

@@ -1,7 +1,8 @@
 """paged_attention's share of its roofline over the traced span: the least
-time of every call (live KV read once, bench/work.py) over the kernel's
-device time, with the ops that staged its operands into VMEM.  Nothing is
-read when the trace's calls do not match the step log's."""
+time of every call (live KV read once, counted by the architecture's step
+work, bench/arch) over the kernel's device time, with the ops that staged
+its operands into VMEM.  Nothing is read when the trace's calls do not
+match the traced steps' `attn_calls`."""
 from bench import trace_reduce
 from bench.stats import on_device, traced_work
 
@@ -11,8 +12,7 @@ def read(ctx):
         return None
     tot, n = trace_reduce.staged_kernel_time(ctx.trace, "paged_attn")
     pre, dec = traced_work(ctx)
-    calls = (len(pre) + len(dec)) * ctx.shape.layers
-    if not n or n != calls:
+    if not n or n != sum(w["attn_calls"] for w in pre + dec):
         return None
     p = ctx.peaks
     least = sum(w["attn"].least_s(p.flops_per_s, p.hbm_bytes_per_s)

@@ -1,14 +1,17 @@
-"""A benchmark configuration, by name: its published config, the program's
-`ModelConfig` built from it, and weights made on the device from a seed.
+"""A benchmark configuration, by name: its published config, the module of
+its architecture, and weights made on the device from a seed.
 
 `bench/configs/<name>.json` holds the model's published `config.json` keys
 as they are run (a key cut for one chip is listed under `reduced` with its
 published value), plus `source`, `assumed`, `deployment` and the name of
-its plain reference under `bench/references/`.
+its plain reference under `bench/references/`.  What depends on the
+architecture is in `bench/arch/<model_type>.py` (`arch_for`).
 """
 from __future__ import annotations
 
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 import jax
@@ -29,32 +32,29 @@ def load_config(name: str) -> dict:
     return json.loads(path.read_text())
 
 
-def model_config(name: str, hf: dict):
-    """The program's ModelConfig for a Qwen2-architecture config.json."""
-    from repro.configs.base import ModelConfig
-    if hf["model_type"] != "qwen2" or hf.get("use_sliding_window"):
-        raise ValueError(f"{name}: only full-attention qwen2 configs are "
-                         f"mapped, got {hf['model_type']}")
-    cfg = ModelConfig(
-        name=name, family="dense",
-        d_model=hf["hidden_size"], num_layers=hf["num_hidden_layers"],
-        num_heads=hf["num_attention_heads"],
-        num_kv_heads=hf["num_key_value_heads"],
-        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
-        d_ff=hf["intermediate_size"], vocab_size=hf["vocab_size"],
-        pattern=("dense",), qkv_bias=True, rope_theta=hf["rope_theta"],
-        norm_eps=hf["rms_norm_eps"],
-        tie_embeddings=hf["tie_word_embeddings"], dtype=hf["torch_dtype"])
-    return cfg
+def load_module(path: Path):
+    """Import a file of the benchmark by path (metric names hold dots), as
+    the module `bench_<directory>_<name>`."""
+    name = f"bench_{path.parent.name}_{path.stem}"
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    # registered, as dataclasses look their module up while it runs
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def smoke_hf(hf: dict) -> dict:
-    """The config at a width the CPU tests can run, with the architecture
-    kept: GQA stays grouped, MHA stays whole."""
-    kv = 2 if hf["num_key_value_heads"] < hf["num_attention_heads"] else 4
-    return dict(hf, hidden_size=128, num_hidden_layers=2,
-                num_attention_heads=4, num_key_value_heads=kv,
-                intermediate_size=256, vocab_size=512)
+def arch_for(hf: dict):
+    """The module of the configuration's architecture,
+    `bench/arch/<model_type>.py`: its config mapping, smoke width, kernel
+    weights and step work (the names are listed in `bench/arch/qwen2.py`)."""
+    path = BENCH / "arch" / f"{hf['model_type']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no architecture module for model_type {hf['model_type']!r}: "
+            f"bench/arch/{hf['model_type']}.py is missing")
+    return load_module(path)
 
 
 def _leaf_init(name: str, key, shape, dtype):

@@ -3,7 +3,8 @@
   python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is an entry of `workloads` in BENCHMARK.json: a configuration
-(`bench/configs/<config>.json`) under a traffic mix
+(`bench/configs/<config>.json`, whose `model_type` names its
+architecture's module, `bench/arch/<model_type>.py`) under a traffic mix
 (`bench/traffic/<mix>.json`), with its serving settings (slots, max_len,
 num_blocks, an open loop's rate_per_s, and any further `ServeConfig`
 fields under "serve") and the limit of its check in
@@ -32,7 +33,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import os
@@ -72,15 +72,6 @@ def load_json(path: Path) -> dict:
     return json.loads(path.read_text())
 
 
-def load_module(path: Path):
-    """Import a file of the benchmark by path (metric names hold dots)."""
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def cell_metrics(bench: dict, workload: str, kind: str) -> "list[dict]":
     """The metrics of `kind` ("end_to_end" or "per_layer") the cell
     reports: those that list it, or list no cells and (for a per-layer
@@ -106,7 +97,8 @@ class Context:
     setup_s: float
     rows: list                        # the engine's ledger rows, window
     steps: list                       # each window step's calls (adapter)
-    shape: object                     # bench.work.Shape
+    arch: object                      # the module bench/arch/<model_type>.py
+    shape: object                     # its Shape
     slots: int
     peaks: object                     # bench.peaks.Peaks (None on CPU)
     memory_peak_bytes: int
@@ -327,6 +319,7 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
                 **(cell_overrides or {}))
     from bench import gen, model
     hf = model.load_config(wl["config"])
+    arch = model.arch_for(hf)
     mix = gen.load_mix(wl["traffic"])
 
     # the compile cache lives inside the checkout, at a fixed path (the
@@ -345,7 +338,7 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
     from repro.launch.compile_cache import use_compile_cache
     use_compile_cache()
 
-    from bench import adapter, peaks, work
+    from bench import adapter, peaks
     from repro.kernels.ops import resolve_dense_mode
     from repro.models import transformer as tf
     from repro.serving import ServeConfig, make_engine
@@ -353,12 +346,12 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
     if rehearsal:
         # smoke width, short requests, and arrivals frequent enough that a
         # few seconds' window finishes some, whatever the cell's rate
-        hf = model.smoke_hf(hf)
+        hf = arch.smoke_hf(hf)
         cell = dict(cell, max_len=256, num_blocks=0,
                     rate_per_s=max(2.0, cell.get("rate_per_s", 0.0)))
         mix = dict(mix, prompt=dict(mix["prompt"], min=8, max=96, median=32),
                    output=dict(mix["output"], min=2, max=16, median=6))
-    cfg = model.model_config(wl["config"], hf)
+    cfg = arch.model_config(wl["config"], hf)
     mode = "interpret" if rehearsal else "auto"
     want = ("interpret", "interpret") if rehearsal else ("kernel", "pallas")
     params = model.make_weights(tf.param_specs(cfg), args.seed)
@@ -368,8 +361,9 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
         paged_attn_kernel=mode, **cell.get("serve", {})))
     routes = {str(p[-1].key): resolve_dense_mode(mode, leaf[0])
               for p, leaf in jax.tree_util.tree_leaves_with_path(
-                  params["blocks"]) if str(p[-1].key).startswith("w_")}
-    if set(routes.values()) != {want[0]} or engine.paged_attn_mode != want[1]:
+                  params["blocks"]) if str(p[-1].key) in arch.KERNEL_WEIGHTS}
+    if (set(routes) != arch.KERNEL_WEIGHTS or set(routes.values()) != {want[0]}
+            or engine.paged_attn_mode != want[1]):
         raise RuntimeError(f"kernel routing resolved to {routes}, paged "
                            f"attention to {engine.paged_attn_mode}")
 
@@ -418,7 +412,7 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
         mem_peak = max(s.get("peak_bytes_in_use", 0) for s in stats)
         ctx = Context(
             due=h.due, tokens=h.tokens, t0=t0, t_end=t_end, setup_s=setup_s,
-            rows=rows, steps=steps, shape=work.Shape.from_hf(hf),
+            rows=rows, steps=steps, arch=arch, shape=arch.Shape.from_hf(hf),
             slots=cell["slots"],
             peaks=None if rehearsal else peaks.peaks_for(dev.device_kind),
             memory_peak_bytes=mem_peak, traced=traced)
@@ -438,7 +432,8 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
     metrics = {}
     for m in cell_metrics(bench, args.workload,
                           "per_layer" if args.trace else "end_to_end"):
-        value = load_module(BENCH / "metrics" / f"{m['name']}.py").read(ctx)
+        value = model.load_module(BENCH / "metrics" / f"{m['name']}.py"
+                                  ).read(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
@@ -449,7 +444,7 @@ def run(argv=None, *, cell_overrides=None, check=True, control=False
     engine.caches = None
     del engine, h.engine
     gc.collect()
-    ref = load_module(BENCH / "references" / f"{hf['reference']}.py")
+    ref = model.load_module(BENCH / "references" / f"{hf['reference']}.py")
     worst, served, checked, answers, own = (
         check_answers(outputs, h.requests, hf, params, ref, args.seed,
                       control) if check else (math.nan, 0, 0, {}, math.nan))

@@ -39,23 +39,16 @@ def on_device(ctx) -> bool:
 
 
 def traced_work(ctx):
-    """Per traced step program: the work its kernels and its model need
-    (`bench.work`), as lists over the prefill and the decode calls."""
-    from bench import work
+    """Per traced step program: the work its kernels and its model need,
+    and how many calls of each kernel it makes (`ctx.arch`'s
+    `prefill_chunk` and `decode_step`), as lists over the prefill and the
+    decode calls."""
     pre, dec = [], []
     for step in ctx.traced_steps():
         if step["prefill"] is not None:
             start, rows, real = step["prefill"]
-            w = work.prefill_chunk(ctx.shape, rows, start)
-            w["model_flops"] = work.model_flops(
-                ctx.shape, real, work.attn_calls(ctx.shape, [start + real],
-                                                 real) if real else work.Work())
-            pre.append(w)
+            pre.append(ctx.arch.prefill_chunk(ctx.shape, rows, start, real))
         if step["decode"]:
-            lanes = step["decode"]
-            ctxs = lanes + [1] * (ctx.slots - len(lanes))
-            w = work.decode_step(ctx.shape, ctx.slots, ctxs)
-            w["model_flops"] = work.model_flops(
-                ctx.shape, len(lanes), work.attn_calls(ctx.shape, lanes, 1))
-            dec.append(w)
+            dec.append(ctx.arch.decode_step(ctx.shape, ctx.slots,
+                                            step["decode"]))
     return pre, dec

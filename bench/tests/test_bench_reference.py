@@ -23,8 +23,10 @@ PROMPT, NEW = 70, 6          # three 32-token chunks, the last one partial
 def _serve(name, seed):
     from repro.models import transformer as tf
     from repro.serving import ServeConfig, make_engine
-    hf = model.smoke_hf(model.load_config(name))
-    cfg = model.model_config(name, hf)
+    hf = model.load_config(name)
+    arch = model.arch_for(hf)
+    hf = arch.smoke_hf(hf)
+    cfg = arch.model_config(name, hf)
     params = model.make_weights(tf.param_specs(cfg), seed)
     engine = make_engine(cfg, params, ServeConfig(
         slots=2, max_len=128, dense_kernel="interpret",
@@ -82,8 +84,10 @@ def test_reference_pads_without_reaching_back():
     """Padding a sequence to the reference's block size changes nothing
     at earlier positions (causal attention)."""
     from repro.models import transformer as tf
-    hf = model.smoke_hf(model.load_config("qwen1.5-0.5b"))
-    cfg = model.model_config("qwen1.5-0.5b", hf)
+    hf = model.load_config("qwen1.5-0.5b")
+    arch = model.arch_for(hf)
+    hf = arch.smoke_hf(hf)
+    cfg = arch.model_config("qwen1.5-0.5b", hf)
     params = model.make_weights(tf.param_specs(cfg), 5)
     toks = np.arange(1, 41)
     a = qwen2.logits(hf, params, toks, [10, 39])

@@ -12,7 +12,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench import run, spans
+from bench import model, run, spans
 from bench import trace_reduce as tr
 
 DATA = Path(__file__).parent / "data"
@@ -121,7 +121,7 @@ def _ctx(rows, on_chip=True):
 
 
 def test_step_d2h_mb_reads_the_ledger():
-    read = run.load_module(run.BENCH / "metrics" / "step_d2h_mb.py").read
+    read = model.load_module(run.BENCH / "metrics" / "step_d2h_mb.py").read
     rows = [{"d2h_bytes": 9_732_096}, {"d2h_bytes": 10_340_352},
             {"d2h_bytes": 0}]
     assert read(_ctx(rows)) == pytest.approx(

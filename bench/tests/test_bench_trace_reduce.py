@@ -77,3 +77,21 @@ def test_idle_gaps_by_annotation(trace):
         "none": (3450 + 4310 + 4020 + 8649) * 1e-9}, abs=1e-12)
     assert sum(gaps.values()) == pytest.approx(
         (hi - lo - 29527) * 1e-9, abs=1e-12)
+
+
+# What each recorded trace gave before `gpp_matmul_grouped` had an entry of
+# its own: (kernel_time, staged_kernel_time) of each kernel.  Neither trace
+# holds a grouped call.
+BEFORE = {
+    "two_steps": {"gpp_matmul": ((13772.0, 2), (13772.0, 2)),
+                  "paged_attn": ((11177.0, 2), (15715.0, 2)),
+                  "gpp_matmul_grouped": ((0.0, 0), (0.0, 0))},
+    "serve_spans": {k: ((0.0, 0), (0.0, 0)) for k in tr.KERNELS},
+}
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_recorded_traces_read_as_before(name):
+    t = tr.load(DATA.parent / f"{name}.xplane.pb")
+    assert {k: (tr.kernel_time(t, k), tr.staged_kernel_time(t, k))
+            for k in tr.KERNELS} == BEFORE[name]

@@ -1,18 +1,21 @@
-"""Work counts and peaks: the numbers every roofline share divides by."""
+"""Work counts and peaks: the numbers every roofline share divides by.
+The per-call counts are `bench/work.py`'s, a Qwen2 model's sizes
+`bench/arch/qwen2.py`'s."""
 import pytest
 
 from bench import model, peaks, work
+from bench.arch import qwen2
 
 
 @pytest.mark.parametrize("name,gb", [("qwen2-7b-14L", 7.62),
                                      ("qwen1.5-0.5b", 0.93)])
 def test_decode_streams_the_weights(name, gb):
-    shape = work.Shape.from_hf(model.load_config(name))
+    shape = qwen2.Shape.from_hf(model.load_config(name))
     assert round(2 * shape.streamed_params() / 1e9, 2) == gb
 
 
 def test_qwen2_7b_layer_and_head_by_hand():
-    s = work.Shape.from_hf(model.load_config("qwen2-7b-14L"))
+    s = qwen2.Shape.from_hf(model.load_config("qwen2-7b-14L"))
     # q 3584x3584, k and v 3584x512, o 3584x3584, gate/up/down 3584x18944
     assert s.layer_params() == (2 * 3584 * 3584 + 2 * 3584 * 512
                                 + 3 * 3584 * 18944) == 233_046_016

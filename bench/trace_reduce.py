@@ -16,18 +16,57 @@ import dataclasses
 import re
 from pathlib import Path
 
-# How each kernel is found among the device's operations.  The Pallas calls
-# carry no name of their own in the trace (every one is a
-# `tpu_custom_call`), so a kernel is told by its operands: the paged
-# attention kernel takes its block tables and positions as two int32
-# scalar-prefetch operands first, the GPP matmul takes its activations and
-# weights first.  Kept in this one place.
+# How each kernel is found among the device's operations, kept in this one
+# place.  Every Pallas call is a `tpu_custom_call`.  A named call (the
+# Pallas call's `name`, since the kernels carry one) is told by its
+# instruction's name, `%gpp_matmul.12 = ...`.  An unnamed one is told by
+# its operands: the paged attention kernel takes its block tables and
+# positions as two int32 scalar-prefetch operands first; the GPP matmuls
+# take their activations first, 3-D (experts, rows, K) for the grouped
+# kernel and 2-D (rows, K) for the plain one.
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
-_PREFETCH = re.compile(r"custom-call\(s32\[[^\]]*\](\{[^}]*\})? \S+, s32\[")
-KERNELS = {
-    "paged_attn": lambda op: CUSTOM_CALL in op and bool(_PREFETCH.search(op)),
-    "gpp_matmul": lambda op: CUSTOM_CALL in op and not _PREFETCH.search(op),
-}
+_INSTR_NAME = re.compile(r"(?:ROOT )?%([\w\-]+)\S* = ")
+_NAMED = {"gpp_matmul": "gpp_matmul",
+          "gpp_matmul_grouped": "gpp_matmul_grouped",
+          "paged_attention": "paged_attn"}
+_TYPE = r"(\w+)\[([^\]]*)\]"
+# a trace's op shows each operand's type in the call, `bf16[8,1024]{1,0:
+# T(8,128)(2,1)} %x.1`; a compiled module's text only in its layout
+# constraints, `operand_layout_constraints={bf16[8,1024]{1,0}, ...}`
+_OPERANDS = (re.compile(rf"custom-call\({_TYPE}(?:\{{[^}}]*\}})? [^\s,]+"
+                        rf"(?:, {_TYPE})?"),
+             re.compile(rf"operand_layout_constraints=\{{{_TYPE}\{{[^}}]*\}}"
+                        rf"(?:, {_TYPE})?"))
+
+
+def _first_operands(op: str) -> "list[tuple[str, int]]":
+    """(dtype, rank) of a call's first two operands (fewer if it has
+    fewer, none where its text shows no types)."""
+    for pattern in _OPERANDS:
+        m = pattern.search(op)
+        if m:
+            dts, dims = m.groups()[0::2], m.groups()[1::2]
+            return [(dt, d.count(",") + 1 if d else 0)
+                    for dt, d in zip(dts, dims) if dt is not None]
+    return []
+
+
+def kernel_of(op: str) -> "str | None":
+    """The kernel an operation's HLO text is a call of, or None."""
+    if CUSTOM_CALL not in op:
+        return None
+    m = _INSTR_NAME.match(op)
+    if m and m.group(1) in _NAMED:
+        return _NAMED[m.group(1)]
+    first = _first_operands(op)
+    if [dt for dt, _ in first] == ["s32", "s32"]:
+        return "paged_attn"
+    return ("gpp_matmul_grouped" if first and first[0][1] == 3
+            else "gpp_matmul")
+
+
+KERNELS = {k: (lambda op, k=k: kernel_of(op) == k)
+           for k in ("paged_attn", "gpp_matmul", "gpp_matmul_grouped")}
 
 HARNESS_ANNOTATIONS = ("harness.submit", "engine.step", "harness.poll",
                        "harness.wait")
@@ -187,9 +226,9 @@ def op_label(hlo: str) -> str:
     """A short, stable label for an op's HLO text: the kernel's name for a
     kernel, else the instruction's name without its number and its result
     type, e.g. `copy bf16[1025,16,16,64]`."""
-    for kernel, match in KERNELS.items():
-        if match(hlo):
-            return kernel
+    kernel = kernel_of(hlo)
+    if kernel:
+        return kernel
     m = re.match(r"%([A-Za-z_\-]+?)(?:\.\d+)? = (\S+?\[[^\]]*\])?", hlo)
     if not m:
         return hlo[:60]

@@ -1,6 +1,6 @@
 """The work a call needs, from shapes alone: operations and HBM bytes of one
-`gpp_matmul` call, one `paged_attention` call, and the calls of one
-serving step of a Qwen2-architecture model (read from its config.json keys).
+`gpp_matmul` call and of one `paged_attention` call.  Which calls a step of
+a model makes is its architecture's (`bench/arch/<model_type>.py`).
 
 These count what the call needs — each weight, each live KV row, each
 activation read once and each output written once — not what an
@@ -48,85 +48,3 @@ def paged_attn_call(contexts, S: int, heads: int, kv_heads: int,
         kv_rows += ctx
     qo = 2 * len(contexts) * S * heads * head_dim
     return Work(flops, (2 * kv_rows * kv_heads * head_dim + qo) * itemsize)
-
-
-@dataclasses.dataclass(frozen=True)
-class Shape:
-    """The sizes of a Qwen2-architecture model that fix its work."""
-    d: int
-    f: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    layers: int
-    vocab: int
-    tied: bool
-    itemsize: int = 2
-
-    @classmethod
-    def from_hf(cls, hf: dict) -> "Shape":
-        return cls(d=hf["hidden_size"], f=hf["intermediate_size"],
-                   heads=hf["num_attention_heads"],
-                   kv_heads=hf["num_key_value_heads"],
-                   head_dim=hf["hidden_size"] // hf["num_attention_heads"],
-                   layers=hf["num_hidden_layers"], vocab=hf["vocab_size"],
-                   tied=hf["tie_word_embeddings"])
-
-    def projections(self) -> "list[tuple[int, int, bool]]":
-        """(K, N, bias) of each projection of one layer: q, k, v, o, gate,
-        up, down."""
-        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
-        return [(self.d, q, True), (self.d, kv, True), (self.d, kv, True),
-                (q, self.d, False), (self.d, self.f, False),
-                (self.d, self.f, False), (self.f, self.d, False)]
-
-    def layer_params(self) -> int:
-        return sum(k * n for k, n, _ in self.projections())
-
-    def head_params(self) -> int:
-        return self.vocab * self.d
-
-    def streamed_params(self) -> int:
-        """Parameters a step reads: every layer's projections and the LM
-        head (tied or not, the head is read whole; the embedding rows a
-        step looks up are negligible)."""
-        return self.layers * self.layer_params() + self.head_params()
-
-    def kv_bytes_per_token(self) -> int:
-        return 2 * self.layers * self.kv_heads * self.head_dim * self.itemsize
-
-
-def gpp_calls(shape: Shape, M: int) -> Work:
-    """Every `gpp_matmul` call of one step program over M rows."""
-    one = Work()
-    for K, N, bias in shape.projections():
-        one = one + gpp_call(M, K, N, itemsize=shape.itemsize, bias=bias)
-    return Work(one.flops * shape.layers, one.bytes * shape.layers)
-
-
-def attn_calls(shape: Shape, contexts, S: int) -> Work:
-    """Every `paged_attention` call of one step program."""
-    one = paged_attn_call(contexts, S, shape.heads, shape.kv_heads,
-                          shape.head_dim, itemsize=shape.itemsize)
-    return Work(one.flops * shape.layers, one.bytes * shape.layers)
-
-
-def model_flops(shape: Shape, tokens: int, attn: Work) -> float:
-    """Model operations of a step that computes `tokens` rows through every
-    layer and the LM head on `tokens` rows: 2 per streamed parameter per
-    row, plus attention.  Pad rows and inactive lanes are not work."""
-    return 2.0 * shape.streamed_params() * tokens + attn.flops
-
-
-def decode_step(shape: Shape, slots: int, contexts) -> dict:
-    """One decode call: `slots` rows through the projections (the call's
-    shape), `contexts` the live context of each lane it serves (inactive
-    lanes: 1, the null row they touch).  `active` is how many are real."""
-    attn = attn_calls(shape, contexts, 1)
-    return {"gpp": gpp_calls(shape, slots), "attn": attn}
-
-
-def prefill_chunk(shape: Shape, chunk: int, start: int) -> dict:
-    """One prefill-chunk call: `chunk` rows at positions start.. of one lane."""
-    attn = attn_calls(shape, [start + chunk], chunk)
-    return {"gpp": gpp_calls(shape, chunk), "attn": attn}
