@@ -9,6 +9,23 @@ from repro.core.streamer import StreamSettings
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling, as a config.json's `rope_scaling` of type
+    "yarn" gives it (DeepSeek-V2): frequencies past the
+    `original_max_position_embeddings` context are interpolated by
+    `factor`, blended over the dims between the `beta_fast` and `beta_slow`
+    rotation counts, and cos/sin and the attention scale carry the
+    `mscale` / `mscale_all_dim` terms (`models.layers.rope`,
+    `models.attention`)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense|moe|ssm|hybrid|audio|vlm
@@ -25,6 +42,9 @@ class ModelConfig:
     # attention
     qkv_bias: bool = False
     rope_theta: float = 1e6
+    rope_scaling: YarnScaling | None = None
+    rope_interleave: bool = False    # rotate lanes (2j, 2j+1) as a pair
+                                     # (DeepSeek-V2) instead of (j, j+hd/2)
     window_size: int | None = None
     # MLA
     kv_lora_rank: int | None = None
@@ -35,7 +55,8 @@ class ModelConfig:
     experts_per_token: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int | None = None
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25 # training; serving drops no token
+    moe_norm_topk_prob: bool = True  # renormalise the top-k gate weights
     moe_serve_resident: bool = False # serving: experts resident, E over model
                                      # x d_ff over data (set by the serve steps)
     moe_ep_mode: str = "tp"          # tp: experts over model axis (+FSDP);

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 
 from repro.core.schedule import round_up
 from repro.kernels.ops import dense, paged_attn, resolve_paged_attn_mode
-from repro.models.layers import sds, rope
+from repro.models.layers import rmsnorm_specs, rope, sds, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -32,6 +32,8 @@ class AttnConfig:
     head_dim: int
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    rope_scaling: object = None        # configs.base.YarnScaling or None
+    rope_interleave: bool = False      # DeepSeek-V2 lane pairing (layers.rope)
     window: int | None = None          # sliding-window size (None = full)
     # MLA
     kv_lora_rank: int | None = None
@@ -52,6 +54,23 @@ class AttnConfig:
     def is_mla(self) -> bool:
         return self.kv_lora_rank is not None
 
+    def rope(self, x, positions):
+        return rope(x, positions, self.rope_theta, self.rope_scaling,
+                    self.rope_interleave)
+
+    @property
+    def mla_scale(self) -> float:
+        """MLA's softmax scale: 1/sqrt(nope + rope), times YaRN's
+        mscale(factor, mscale_all_dim)^2 where the config scales rope
+        (DeepSeek-V2: 1.5896 at factor 40, mscale_all_dim 0.707) — the same
+        in the reference, paged-reference and absorbed kernel forms."""
+        scale = 1.0 / math.sqrt(self.head_dim + self.rope_head_dim)
+        s = self.rope_scaling
+        if s is not None and s.mscale_all_dim:
+            m = yarn_mscale(s.factor, s.mscale_all_dim)
+            scale *= m * m
+        return scale
+
 
 # ---------------------------------------------------------------------------
 # params
@@ -65,12 +84,14 @@ def attn_specs(c: AttnConfig):
             "w_uk": sds((c.kv_lora_rank, c.num_heads, nope), c.dtype),
             "w_uv": sds((c.kv_lora_rank, c.num_heads, nope), c.dtype),
             "w_o": sds((c.num_heads, nope, c.d_model), c.dtype),
-            "kv_norm": sds((c.kv_lora_rank,), c.dtype),
+            # the latent's RMSNorm, laid out as every norm ({"scale"}) so
+            # that initializers treat it as one
+            "kv_norm": rmsnorm_specs(c.kv_lora_rank, c.dtype),
         }
         if c.q_lora_rank:
             sp["w_dq"] = sds((c.d_model, c.q_lora_rank), c.dtype)
             sp["w_uq"] = sds((c.q_lora_rank, c.num_heads, nope + c.rope_head_dim), c.dtype)
-            sp["q_norm"] = sds((c.q_lora_rank,), c.dtype)
+            sp["q_norm"] = rmsnorm_specs(c.q_lora_rank, c.dtype)
         else:
             sp["w_q"] = sds((c.d_model, c.num_heads, nope + c.rope_head_dim), c.dtype)
         return sp
@@ -297,8 +318,8 @@ def gqa_project_qkv(p, c: AttnConfig, x, positions):
     q = dense(x, p["w_q"], bias=bq, mode=c.dense_mode)
     k = dense(x, p["w_k"], bias=bk, mode=c.dense_mode)
     v = dense(x, p["w_v"], bias=bv, mode=c.dense_mode)
-    q = rope(q, positions, c.rope_theta)
-    k = rope(k, positions, c.rope_theta)
+    q = c.rope(q, positions)
+    k = c.rope(k, positions)
     return q, k, v
 
 
@@ -547,8 +568,7 @@ def _mla_paged_attend(p, c: AttnConfig, q, ckv, kr, tables, positions,
     q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, p["w_uk"]).astype(q.dtype)
     q_eff = jnp.concatenate([q_abs, q_rope], axis=-1)
     out_lat = paged_attn(q_eff, ckv, kr, tables, positions,
-                         num_kv_heads=1, mla=True,
-                         scale=1.0 / math.sqrt(nope + c.rope_head_dim),
+                         num_kv_heads=1, mla=True, scale=c.mla_scale,
                          mode=mode)
     out = jnp.einsum("bshr,rhn->bshn", out_lat, p["w_uv"]).astype(q.dtype)
     return dense(out, p["w_o"], mode=c.dense_mode, contract_dims=2)
@@ -610,12 +630,12 @@ def _mla_q(p, c: AttnConfig, x, positions):
     from repro.models.layers import rmsnorm
     nope = c.head_dim
     if c.q_lora_rank:
-        cq = rmsnorm({"scale": p["q_norm"]}, dense(x, p["w_dq"], mode=c.dense_mode))
+        cq = rmsnorm(p["q_norm"], dense(x, p["w_dq"], mode=c.dense_mode))
         q = dense(cq, p["w_uq"], mode=c.dense_mode)
     else:
         q = dense(x, p["w_q"], mode=c.dense_mode)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = rope(q_rope, positions, c.rope_theta)
+    q_rope = c.rope(q_rope, positions)
     return jnp.concatenate([q_nope, q_rope], axis=-1)
 
 
@@ -623,8 +643,8 @@ def _mla_latent(p, c: AttnConfig, x, positions):
     from repro.models.layers import rmsnorm
     d = dense(x, p["w_dkv"], mode=c.dense_mode)
     c_kv, k_rope = d[..., : c.kv_lora_rank], d[..., c.kv_lora_rank:]
-    c_kv = rmsnorm({"scale": p["kv_norm"]}, c_kv)
-    k_rope = rope(k_rope[..., None, :], positions, c.rope_theta)[..., 0, :]
+    c_kv = rmsnorm(p["kv_norm"], c_kv)
+    k_rope = c.rope(k_rope[..., None, :], positions)[..., 0, :]
     return c_kv, k_rope
 
 
@@ -636,7 +656,7 @@ def _mla_attend(p, c: AttnConfig, q, c_kv, k_rope, mask):
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (*k_nope.shape[:3], c.rope_head_dim))], axis=-1
     )
-    out = _sdpa(q, k, v, mask, 1.0 / math.sqrt(nope + c.rope_head_dim))
+    out = _sdpa(q, k, v, mask, c.mla_scale)
     out = out[..., :nope]  # v has nope dims; _sdpa padded? no: v dims = nope
     return dense(out, p["w_o"], mode=c.dense_mode, contract_dims=2)
 
@@ -645,13 +665,12 @@ def mla_forward(p, c: AttnConfig, x, positions):
     B, S, _ = x.shape
     q = _mla_q(p, c, x, positions)
     c_kv, k_rope = _mla_latent(p, c, x, positions)
-    nope = c.head_dim
     k_nope = dense(c_kv, p["w_uk"], mode=c.dense_mode)
     v = dense(c_kv, p["w_uv"], mode=c.dense_mode)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
                                   (*k_nope.shape[:3], c.rope_head_dim))], axis=-1)
-    out = _sdpa_chunked(q, k, v, 1.0 / math.sqrt(nope + c.rope_head_dim))
+    out = _sdpa_chunked(q, k, v, c.mla_scale)
     return dense(out, p["w_o"], mode=c.dense_mode, contract_dims=2)
 
 

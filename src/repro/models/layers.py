@@ -8,8 +8,11 @@ router, rotary), weights are stored in cfg.dtype (bf16 by default).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.ops import dense
 
@@ -68,14 +71,62 @@ def rmsnorm(p: Pytree, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 # rotary embeddings
 # ---------------------------------------------------------------------------
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Apply rotary embedding.  x: (..., S, H, hd); positions: (..., S)."""
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature term, transformers' `yarn_get_mscale`:
+    0.1 * mscale * ln(factor) + 1 (1 when the context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(hd: int, theta: float, s) -> np.ndarray:
+    """(hd/2,) YaRN rotary frequencies (transformers'
+    `DeepseekV2YarnRotaryEmbedding`): each dim's frequency is blended
+    between theta^(-2j/hd) and that over `factor` by a linear ramp between
+    the dims that turn `beta_fast` and `beta_slow` times over the original
+    context; dims below the ramp keep their frequency, dims above it are
+    interpolated."""
+    extra = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
+
+    def dim_of(rotations):
+        return (hd * math.log(s.original_max_position_embeddings
+                              / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(s.beta_fast)), 0)
+    high = min(math.ceil(dim_of(s.beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(hd // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    return (extra / s.factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         scaling=None, interleave: bool = False) -> jnp.ndarray:
+    """Apply rotary embedding.  x: (..., S, H, hd); positions: (..., S).
+
+    Rotate-half: lane j turns with lane j + hd/2.  `interleave` pairs lanes
+    2j and 2j+1 instead, laid out de-interleaved on the way out (lanes 0, 2,
+    4, .. then 1, 3, ..), as DeepSeek-V2 does; q and k share the layout, so
+    their products are those of the pairing.  `scaling` (a
+    `configs.base.YarnScaling`) takes YaRN's frequencies and its cos/sin
+    factor mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32) * (jnp.log(theta) / half))
+    if scaling is None:
+        freqs = jnp.exp(-jnp.arange(0, half, dtype=jnp.float32)
+                        * (jnp.log(theta) / half))
+    else:
+        freqs = jnp.asarray(yarn_inv_freq(hd, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]  # broadcast over heads
     sin = jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 

@@ -6,8 +6,11 @@ term.  Here dispatch is gather/scatter (bytes, not FLOPs), and expert FFNs
 are batched einsums over (E, C, D) — HLO FLOPs == active-expert FLOPs, which
 is what 6*N_active*D accounting expects.
 
-Capacity: C = ceil(T * top_k / E * capacity_factor); overflow tokens are
-dropped (their combine weight contributes 0) — standard practice.
+Capacity: in training C = ceil(T * top_k / E * capacity_factor) and
+overflow tokens are dropped (their combine weight contributes 0) — standard
+practice.  The serving steps run dropless (`MoeConfig.dropless`): C is the
+group's token count, which holds every token since a token picks an expert
+at most once, so a served token never depends on its batch-mates.
 
 EP sharding: the (E, C, D) dispatch buffer and (E, D, F) expert weights are
 sharded over the `model` axis on E; XLA inserts the token all-to-alls at the
@@ -36,6 +39,8 @@ class MoeConfig:
     num_shared_experts: int = 0
     shared_d_ff: int | None = None # defaults to d_ff * num_shared
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True    # renormalise the top-k gate weights
+    dropless: bool = False         # capacity = the group's tokens (serving)
     act: str = "swiglu"
     router_dtype: object = jnp.float32
     dtype: object = jnp.bfloat16
@@ -64,6 +69,10 @@ def moe_specs(c: MoeConfig):
 
 
 def capacity(c: MoeConfig, num_tokens: int) -> int:
+    """Slots per expert for a group of `num_tokens`: all of them when
+    dropless, else the capacity factor's share (at least 8)."""
+    if c.dropless:
+        return num_tokens
     cap = math.ceil(num_tokens * c.experts_per_token / c.num_experts
                     * c.capacity_factor)
     return max(8, int(cap))
@@ -76,6 +85,13 @@ def _dispatch_groups(c: MoeConfig, T: int) -> int:
     return max(1, g)
 
 
+def expert_rows(c: MoeConfig, T: int) -> int:
+    """Rows each routed-expert matmul multiplies for T tokens on the local
+    path (`moe_apply` without a mesh): experts x groups x capacity."""
+    G = _dispatch_groups(c, T)
+    return c.num_experts * G * capacity(c, T // G)
+
+
 def _dispatch(p, c: MoeConfig, xt: jnp.ndarray, C: int):
     """Route + scatter one token group into its (E, C, D) buffer (LOCAL —
     the group is a data shard).  Returns (buf, combine metadata)."""
@@ -86,7 +102,8 @@ def _dispatch(p, c: MoeConfig, xt: jnp.ndarray, C: int):
                    p["router"].astype(c.router_dtype), mode=c.dense_kernel)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, k)                      # (Tg, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if c.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
 
     flat_e = top_e.reshape(-1)                                  # (Tg*k,)
     order = jnp.argsort(flat_e)                                 # stable
@@ -122,8 +139,8 @@ def _expert_ffn(p, c: MoeConfig, buf: jnp.ndarray) -> jnp.ndarray:
     over `data`.  We GATHER the data shards explicitly before the matmuls —
     the paper's write/compute streaming — because letting the partitioner
     handle the sharded contraction dim makes it all-reduce f32 ACTIVATIONS
-    over data instead (measured 16x more bytes on kimi-k2: EXPERIMENTS.md
-    §Perf).  The weights cost 2 GB/layer (bf16); the activations 30+ GB.
+    over data instead.  On kimi-k2 the weights cost 2 GB/layer (bf16); the
+    activations 30+ GB (count).
 
     The token-group axis G folds into the per-expert row dim (E, G*C, D) so
     each expert's weights stream from HBM once for ALL groups — the grouped
@@ -178,8 +195,8 @@ def _moe_shard_map(p, c: MoeConfig, x: jnp.ndarray, mesh) -> jnp.ndarray:
     `all_gather` of the data-sharded expert weights is the "rewrite", the
     expert einsums the "compute"; bwd transposes to reduce-scatter.  We use
     shard_map because the SPMD partitioner's implicit choices for this block
-    (activation psums fwd, replicate-then-slice bwd) cost 10-60x more bytes
-    — measured in EXPERIMENTS.md §Perf."""
+    (activation psums fwd, replicate-then-slice bwd) move far more bytes
+    than the weight gathers (the count in `_expert_ffn`)."""
     from jax.sharding import PartitionSpec as P
     B, S, D = x.shape
     dp = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
@@ -217,7 +234,7 @@ def _moe_shard_map_serve(p, c: MoeConfig, x: jnp.ndarray, mesh) -> jnp.ndarray:
     (tiny at decode: B x 1) are replicated inside the block; each shard
     computes its (E_local x F_slice) partial and one small psum over
     (data, model) combines.  kimi-k2: ~44 MB psum/layer vs 2.1 GB of weight
-    gathers per token (EXPERIMENTS.md §Perf cell D)."""
+    gathers per token (count)."""
     from jax.sharding import PartitionSpec as P
     B, S, D = x.shape
     T = B * S

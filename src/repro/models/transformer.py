@@ -51,6 +51,8 @@ def _attn_cfg(cfg: ModelConfig, kind: str) -> attn.AttnConfig:
         head_dim=cfg.resolved_head_dim,
         qkv_bias=cfg.qkv_bias,
         rope_theta=cfg.rope_theta,
+        rope_scaling=cfg.rope_scaling,
+        rope_interleave=cfg.rope_interleave,
         window=window,
         kv_lora_rank=cfg.kv_lora_rank,
         q_lora_rank=cfg.q_lora_rank,
@@ -79,7 +81,9 @@ def _xlstm_cfg(cfg: ModelConfig) -> xlstm_mod.XlstmConfig:
     )
 
 
-def _moe_cfg(cfg: ModelConfig) -> moe_mod.MoeConfig:
+def _moe_cfg(cfg: ModelConfig, dropless: bool = False) -> moe_mod.MoeConfig:
+    """The MoE layer's config.  `dropless`: the serving steps', whose
+    capacity holds every token of a group (`moe.capacity`)."""
     return moe_mod.MoeConfig(
         d_model=cfg.d_model,
         d_ff=cfg.moe_d_ff or cfg.d_ff,
@@ -87,6 +91,8 @@ def _moe_cfg(cfg: ModelConfig) -> moe_mod.MoeConfig:
         experts_per_token=cfg.experts_per_token,
         num_shared_experts=cfg.num_shared_experts,
         capacity_factor=cfg.moe_capacity_factor,
+        norm_topk_prob=cfg.moe_norm_topk_prob,
+        dropless=dropless,
         act=cfg.act,
         dtype=cfg.jdtype,
         ep_mode=cfg.moe_ep_mode,
@@ -521,6 +527,32 @@ def paged_cache_specs(cfg: ModelConfig, num_blocks: int, block_size: int) -> Pyt
     return caches
 
 
+def _ffn_paged(cfg, base, p, x):
+    """The feed-forward half of a served block.  An MoE layer drops no
+    token here: its capacity holds every token of a group, so what a token
+    gets is its own experts' output, whatever its batch-mates route to."""
+    h = rmsnorm(p["ln2"], x)
+    if base == "moe":
+        return moe_mod.moe_apply(p["moe"], _moe_cfg(cfg, dropless=True), h)
+    return mlp(p["mlp"], h, cfg.act, dense_mode=cfg.dense_kernel)
+
+
+def served_moe_rows(cfg: ModelConfig, rows: int, real: int
+                    ) -> "tuple[int, int]":
+    """(rows routed, rows computed) of the routed-expert matmuls of one
+    served step program over `rows` tokens, `real` of them real, summed
+    over the MoE layers: real x experts per token, and experts x groups x
+    capacity (`moe.expert_rows`).  (0, 0) for a model without MoE layers."""
+    layers = (sum(k.startswith("moe") for k in cfg.prefix_pattern)
+              + sum(k.startswith("moe") for k in cfg.pattern)
+              * cfg.num_superblocks)
+    if not layers:
+        return 0, 0
+    c = _moe_cfg(cfg, dropless=True)
+    return (layers * c.experts_per_token * real,
+            layers * moe_mod.expert_rows(c, rows))
+
+
 def _block_prefill_paged(cfg, kind, p, x, cache, table_row, start_pos):
     ac = _attn_cfg(cfg, kind)
     base = kind.split(":")[0]
@@ -533,12 +565,7 @@ def _block_prefill_paged(cfg, kind, p, x, cache, table_row, start_pos):
         h, cache = attn.gqa_prefill_paged(p["attn"], ac, h, cache, row,
                                           start_pos)
     x = x + h
-    h = rmsnorm(p["ln2"], x)
-    if base == "moe":
-        h = moe_mod.moe_apply(p["moe"], _moe_cfg(cfg), h)
-    else:
-        h = mlp(p["mlp"], h, cfg.act, dense_mode=cfg.dense_kernel)
-    return x + h, cache
+    return x + _ffn_paged(cfg, base, p, x), cache
 
 
 def _block_decode_paged(cfg, kind, p, x, cache, tables, positions, active):
@@ -553,12 +580,7 @@ def _block_decode_paged(cfg, kind, p, x, cache, tables, positions, active):
         h, cache = attn.gqa_decode_paged(p["attn"], ac, h, cache, tb,
                                          positions, active)
     x = x + h
-    h = rmsnorm(p["ln2"], x)
-    if base == "moe":
-        h = moe_mod.moe_apply(p["moe"], _moe_cfg(cfg), h)
-    else:
-        h = mlp(p["mlp"], h, cfg.act, dense_mode=cfg.dense_kernel)
-    return x + h, cache
+    return x + _ffn_paged(cfg, base, p, x), cache
 
 
 def _block_verify_paged(cfg, kind, p, x, cache, tables, positions, active,
@@ -574,12 +596,7 @@ def _block_verify_paged(cfg, kind, p, x, cache, tables, positions, active,
         h, cache = attn.gqa_verify_paged(p["attn"], ac, h, cache, tb,
                                          positions, active, nvalid)
     x = x + h
-    h = rmsnorm(p["ln2"], x)
-    if base == "moe":
-        h = moe_mod.moe_apply(p["moe"], _moe_cfg(cfg), h)
-    else:
-        h = mlp(p["mlp"], h, cfg.act, dense_mode=cfg.dense_kernel)
-    return x + h, cache
+    return x + _ffn_paged(cfg, base, p, x), cache
 
 
 def _embed_tokens(params, cfg: ModelConfig, tokens):

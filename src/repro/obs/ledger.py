@@ -61,6 +61,10 @@ STEP_SCHEMA: "tuple[str, ...]" = (
     "hbm_bytes", "attn_bytes_gather", "attn_bytes_stream",
     # device -> host copies (bytes of every array the step read back)
     "d2h_bytes",
+    # routed-expert matmul rows: those that carry a token (real tokens x
+    # experts per token x MoE layers) and those multiplied (MoE layers x
+    # experts x groups x capacity); 0 for a model without MoE layers
+    "moe_rows_routed", "moe_rows_computed",
     # wall time of the step (us; 0.0 when telemetry is disabled)
     "step_wall_us",
 )

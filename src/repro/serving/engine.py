@@ -533,6 +533,7 @@ class ServingEngine:
         programs = 0            # model programs launched: weight streams
         gathered = 0            # lanes whose logical sequence a gather reads
         live = []               # live-block counts of each call's lanes
+        moe_routed = moe_computed = 0
         self._d2h = 0
 
         if plan.prefill:
@@ -556,6 +557,8 @@ class ServingEngine:
             programs += 1
             gathered += 1
             prefill_tokens = len(w.tokens)
+            moe_routed, moe_computed = tf.served_moe_rows(
+                self.cfg, len(w.tokens), w.real_tokens)
             read_tokens += w.start_pos + len(w.tokens)
             if w.final:
                 with phase(trace, "serve.prefill.fetch", tid=lane_tid):
@@ -598,6 +601,9 @@ class ServingEngine:
                 live.append(self._live_blocks())
             programs += 1
             gathered += slots
+            r, c = tf.served_moe_rows(self.cfg, slots,
+                                      len(plan.decode_lanes))
+            moe_routed, moe_computed = moe_routed + r, moe_computed + c
             with phase(trace, "serve.decode.fetch"):
                 logits_np = self._fetch(logits)
             with phase(trace, "serve.sample"):
@@ -644,6 +650,9 @@ class ServingEngine:
                 live.append(self._live_blocks())
             programs += 1
             gathered += slots
+            r, c = tf.served_moe_rows(self.cfg, slots * S,
+                                      int(nvalid.sum()))
+            moe_routed, moe_computed = moe_routed + r, moe_computed + c
             with phase(trace, "serve.verify.fetch"):
                 logits_np = self._fetch(logits)
             with phase(trace, "serve.sample"):
@@ -724,6 +733,8 @@ class ServingEngine:
                 attn_bytes_stream=self.block_size * int(
                     sum((n * group_bytes).sum() for n in live)),
                 d2h_bytes=self._d2h,
+                moe_rows_routed=moe_routed,
+                moe_rows_computed=moe_computed,
                 step_wall_us=(obs.now_us() - step_t0) if obs.enabled else 0.0,
             )
             if obs.enabled:

@@ -142,7 +142,7 @@ def test_gqa_parity(kv_heads, bias):
 def _mla_oracle(p, c, x, pos):
     nope = c.head_dim
     if c.q_lora_rank:
-        cq = rmsnorm({"scale": p["q_norm"]}, x @ p["w_dq"])
+        cq = rmsnorm(p["q_norm"], x @ p["w_dq"])
         q = jnp.einsum("bsr,rhk->bshk", cq, p["w_uq"])
     else:
         q = jnp.einsum("bsd,dhk->bshk", x, p["w_q"])
@@ -150,7 +150,7 @@ def _mla_oracle(p, c, x, pos):
     q = jnp.concatenate([q_nope, rope(q_rope, pos, c.rope_theta)], axis=-1)
     d = x @ p["w_dkv"]
     c_kv, k_rope = d[..., : c.kv_lora_rank], d[..., c.kv_lora_rank:]
-    c_kv = rmsnorm({"scale": p["kv_norm"]}, c_kv)
+    c_kv = rmsnorm(p["kv_norm"], c_kv)
     k_rope = rope(k_rope[..., None, :], pos, c.rope_theta)[..., 0, :]
     k_nope = jnp.einsum("btr,rhk->bthk", c_kv, p["w_uk"])
     v = jnp.einsum("btr,rhk->bthk", c_kv, p["w_uv"])

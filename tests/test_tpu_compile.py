@@ -16,6 +16,7 @@ the described device's kind.
 """
 from __future__ import annotations
 
+import collections
 import re
 
 import pytest
@@ -302,3 +303,24 @@ def test_gqa_pools_keep_their_layout(one_chip, step):
                     "prefill": _compile_prefill}[step]
     compiled = compile_step(one_chip, QWEN2_7B.with_(num_layers=2))
     assert not _pool_relayouts(compiled.as_text())
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_deepseek_v2_lite_steps_fit_one_chip(one_chip, step):
+    """DeepSeek-V2-Lite's first five layers (the benchmark's one-chip
+    stage: the dense layer, four MoE layers with all 64 experts, the
+    embedding and the head) at published widths, YaRN and dropless experts
+    included.  A step program fits one chip, and every MoE layer calls the
+    grouped kernel for gate, up and down, every layer the paged MLA kernel
+    and gpp_matmul for q, the latent, o and the three MLP (shared-expert)
+    projections: in the HLO, the dense layer's calls and one scan body's."""
+    from repro.configs.deepseek_v2_lite_16b import CONFIG as DEEPSEEK
+    compile_step = {"decode": _compile_decode,
+                    "prefill": _compile_prefill}[step]
+    compiled = compile_step(one_chip, DEEPSEEK.with_(num_layers=5))
+    _assert_fits(compiled)
+    calls = collections.Counter(re.findall(
+        r"%(gpp_matmul_grouped|gpp_matmul|paged_attention)[.\d]* = ",
+        compiled.as_text()))
+    assert calls == {"gpp_matmul": 12, "gpp_matmul_grouped": 3,
+                     "paged_attention": 2}
