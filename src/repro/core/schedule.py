@@ -545,6 +545,35 @@ def matmul_vmem_bytes(block_m: int, block_n: int, block_k: int, num_bufs: int,
     )
 
 
+# A free (block_k, block_n) pair aims at a weight tile of this many bytes,
+# no tile above _TILE_MAX_BYTES where a smaller one divides, and rows at
+# least _WIDE_N lanes long where N allows (scripts/gpp_tile_sweep.py).
+_TILE_BYTES = 4 * 1024 * 1024
+_TILE_MAX_BYTES = 6 * 1024 * 1024
+_WIDE_N = 512
+
+
+def _lane_divisors(n: int) -> "list[int]":
+    """The multiples of the 128-lane tile that divide n, ascending."""
+    return [t for t in range(_LANE, n + 1, _LANE) if n % t == 0]
+
+
+def _tile_options(dim: int, pinned: "int | None",
+                  whole: bool) -> "list[tuple[int, int]]":
+    """(tile, padded dim) choices for one dim of the weight.  A pinned tile
+    pads the dim to its next multiple.  Free, the tiles are the 128-lane
+    divisors of the dim, or of the dim padded to whole lanes when it has
+    none; `whole` (block_k) also offers the whole dim as one tile, rounded
+    up to the sublane tile (a block equal to the operand's dim)."""
+    if pinned is not None:
+        return [(pinned, round_up(dim, pinned))]
+    padded = round_up(dim, _LANE)
+    opts = [(t, padded) for t in _lane_divisors(padded)]
+    if whole and dim % _LANE:
+        opts.append((round_up(dim, _SUBLANE), round_up(dim, _SUBLANE)))
+    return opts
+
+
 def plan_matmul_tiles(
     M: int,
     K: int,
@@ -566,13 +595,21 @@ def plan_matmul_tiles(
 ) -> MatmulTilePlan:
     """Pick (block_m, block_n, block_k, num_bufs) under the VMEM budget.
 
-    Caller-pinned dims are honored as-is; unpinned dims start from defaults
-    and shrink (block_k first, then block_m, then ring depth, then block_n)
-    until the working set fits, instead of erroring like the old 1-D kernel.
-    Raises only if the *pinned* configuration cannot fit at minimum sizes of
-    every free dim.  `vmem_budget` defaults to `chip.vmem_limit_bytes`, the
-    scoped limit the kernel is compiled with.  A free block_k that splits K
-    stays a multiple of the 128-lane tile (it is the lane dim of x's block).
+    Caller-pinned dims are honored as-is.  Free block_k and block_n are
+    chosen together among tiles that divide K and N, so the kernel streams
+    the weight as it lies in HBM: a tile of the weight's own dims (128-lane
+    divisors, or the whole of a K that has none) is taken over one that
+    pads it, and among those the pair whose weight tile is nearest
+    `_TILE_BYTES`, no larger than `_TILE_MAX_BYTES` where a smaller one
+    exists, with rows at least `_WIDE_N` lanes long where N allows.  Only
+    a lane dim N with no 128-lane divisor (Mosaic slices HBM in whole
+    lanes) or a pinned tile makes the wrapper zero-pad the weight.
+
+    When the working set does not fit, the planner moves to a smaller
+    divisor pair, then halves block_m, then shrinks the ring depth, instead
+    of erroring.  It raises only if the *pinned* configuration cannot fit
+    at minimum sizes of every free dim.  `vmem_budget` defaults to
+    `chip.vmem_limit_bytes`, the scoped limit the kernel is compiled with.
 
     `timing` (or, when omitted, the cache installed via
     `set_default_timing_cache`) replaces the analytic flops_per_s /
@@ -590,9 +627,10 @@ def plan_matmul_tiles(
         raise ValueError(f"bad matmul shape M={M} K={K} N={N}")
     if num_bufs is not None and num_bufs < 1:
         raise ValueError("num_bufs >= 1")
-    bn = block_n if block_n is not None else min(round_up(N, _LANE), 256)
     bm = block_m if block_m is not None else min(round_up(M, _SUBLANE), 512)
-    bk = block_k if block_k is not None else min(round_up(K, _SUBLANE), 2048)
+    k_opts = _tile_options(K, block_k, whole=True)
+    n_opts = _tile_options(N, block_n, whole=False)
+    wide = min(_WIDE_N, max(bn for bn, _ in n_opts))
 
     def ring_for(bm_, bk_, bn_):
         if num_bufs is not None:
@@ -611,28 +649,43 @@ def plan_matmul_tiles(
             bm_, bn_, bk_, g_, x_itemsize=x_itemsize, w_itemsize=w_itemsize,
             out_itemsize=out_itemsize) <= vmem_budget
 
-    g = ring_for(bm, bk, bn)
-    while not fits(bm, bk, bn, g):
-        if block_k is None and bk > _LANE:
-            bk = max(_LANE, round_up(bk // 2, _LANE))
-        elif block_m is None and bm > _SUBLANE:
-            bm = max(_SUBLANE, round_up(bm // 2, _SUBLANE))
-        elif num_bufs is None and g > 1:
-            g -= 1          # last resort ends at in-situ (G=1), a valid mode
-            continue
-        elif block_n is None and bn > _LANE:
-            bn = max(_LANE, round_up(bn // 2, _LANE))
-        else:
-            used = matmul_vmem_bytes(
-                bm, bn, bk, g, x_itemsize=x_itemsize, w_itemsize=w_itemsize,
-                out_itemsize=out_itemsize)
-            raise ValueError(
-                f"matmul working set {used / 2**20:.1f} MiB exceeds the "
-                f"{vmem_budget / 2**20:.0f} MiB VMEM budget even at minimum "
-                f"free-tile sizes (pinned: block_m={block_m} block_n={block_n} "
-                f"block_k={block_k} num_bufs={num_bufs})"
-            )
-        g = ring_for(bm, bk, bn)
+    def rank(bk_, kp, bn_, np_):
+        tile = bk_ * bn_ * w_itemsize
+        return (kp * np_, tile > _TILE_MAX_BYTES, bn_ < wide,
+                abs(math.log2(tile / _TILE_BYTES)), -bn_)
+
+    def best_pair(bm_, shrink_ring):
+        """The best-ranked (bk, bn, ring) that fits at block_m `bm_`."""
+        best = None
+        for bk_, kp in k_opts:
+            for bn_, np_ in n_opts:
+                g_ = ring_for(bm_, bk_, bn_)
+                while shrink_ring and g_ > 1 and not fits(bm_, bk_, bn_, g_):
+                    g_ -= 1     # last resort ends at in-situ (G=1)
+                if fits(bm_, bk_, bn_, g_):
+                    key = rank(bk_, kp, bn_, np_)
+                    if best is None or key < best[0]:
+                        best = (key, bk_, bn_, g_)
+        return None if best is None else best[1:]
+
+    pick = best_pair(bm, False)
+    while pick is None and block_m is None and bm > _SUBLANE:
+        bm = max(_SUBLANE, round_up(bm // 2, _SUBLANE))
+        pick = best_pair(bm, False)
+    if pick is None and num_bufs is None:
+        pick = best_pair(bm, True)
+    if pick is None:
+        bk, bn = k_opts[0][0], n_opts[0][0]
+        used = matmul_vmem_bytes(
+            bm, bn, bk, num_bufs or 1, x_itemsize=x_itemsize,
+            w_itemsize=w_itemsize, out_itemsize=out_itemsize)
+        raise ValueError(
+            f"matmul working set {used / 2**20:.1f} MiB exceeds the "
+            f"{vmem_budget / 2**20:.0f} MiB VMEM budget even at minimum "
+            f"free-tile sizes (pinned: block_m={block_m} block_n={block_n} "
+            f"block_k={block_k} num_bufs={num_bufs})"
+        )
+    bk, bn, g = pick
 
     # grow an unpinned block_m back toward M while the budget allows: every
     # extra m-pass re-streams the whole weight matrix from HBM, which is

@@ -257,6 +257,39 @@ def _pad2(a: jnp.ndarray, rows: int, cols: int) -> jnp.ndarray:
     return jnp.pad(a, ((0, rows - a.shape[0]), (0, cols - a.shape[1])))
 
 
+def plan_call(M: int, K: int, N: int, x_dtype, w_dtype, *,
+              block_m: int | None = None, block_n: int | None = None,
+              block_k: int | None = None, num_bufs: int | None = None,
+              vmem_budget: int | None = None, chip: ChipSpec = V5E):
+    """The tile plan of one `gpp_matmul` call of x (M, K) @ w (K, N) (or
+    one expert's of a `gpp_matmul_grouped` call) and the padded
+    (Mp, Kp, Np) its operands take: whole tiles of the plan."""
+    plan = plan_matmul_tiles(
+        M, K, N,
+        x_itemsize=jnp.dtype(x_dtype).itemsize,
+        w_itemsize=jnp.dtype(w_dtype).itemsize,
+        out_itemsize=jnp.dtype(x_dtype).itemsize,
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        num_bufs=num_bufs, vmem_budget=vmem_budget or chip.vmem_limit_bytes,
+        chip=chip,
+    )
+    num_m, num_n, num_k = plan.grid(M, N, K)
+    return plan, (num_m * plan.block_m, num_k * plan.block_k,
+                  num_n * plan.block_n)
+
+
+def weight_pad_bytes(M: int, K: int, N: int, x_dtype, w_dtype, *,
+                     experts: int = 1, chip: ChipSpec = V5E) -> int:
+    """Bytes of the zero-padded weight a free-tiled call copies before it
+    streams: `experts` (1 for `gpp_matmul`) padded (Kp, Np) weights, or 0
+    when the planned tiles divide K and N (`plan_call`, the wrappers'
+    own rule)."""
+    _, (_, Kp, Np) = plan_call(M, K, N, x_dtype, w_dtype, chip=chip)
+    if (Kp, Np) == (K, N):
+        return 0
+    return experts * Kp * Np * jnp.dtype(w_dtype).itemsize
+
+
 def gpp_matmul(
     x: jnp.ndarray,
     w: jnp.ndarray,
@@ -284,8 +317,8 @@ def gpp_matmul(
          to the f32 accumulator in the epilogue (int8 streaming).
       activation: optional fused activation: relu | gelu | silu | tanh.
       block_m/block_n/block_k: tile sizes; any left None is planned against
-         the VMEM budget (`core.schedule.plan_matmul_tiles`).  Ragged edges
-         are zero-padded, not errors.
+         the VMEM budget (`core.schedule.plan_matmul_tiles`), dividing K and
+         N where it can.  Ragged edges are zero-padded, not errors.
       num_bufs: ring depth G — 1: in-situ, 2: naive ping-pong, >=3: GPP.
          None: planned from the DMA:compute ratio of one tile.
       vmem_budget: scoped-VMEM limit in bytes the kernel is compiled with and
@@ -305,14 +338,9 @@ def gpp_matmul(
 
     chip = target_chip(interpret)
     vmem_limit = vmem_budget or chip.vmem_limit_bytes
-    plan = plan_matmul_tiles(
-        M, K, N,
-        x_itemsize=x.dtype.itemsize,
-        w_itemsize=w.dtype.itemsize,
-        out_itemsize=jnp.dtype(out_dtype).itemsize,
-        block_m=block_m, block_n=block_n, block_k=block_k,
-        num_bufs=num_bufs, vmem_budget=vmem_limit, chip=chip,
-    )
+    plan, (Mp, Kp, Np) = plan_call(
+        M, K, N, x.dtype, w.dtype, block_m=block_m, block_n=block_n,
+        block_k=block_k, num_bufs=num_bufs, vmem_budget=vmem_limit, chip=chip)
     bm, bn, bk = plan.block_m, plan.block_n, plan.block_k
     num_m, num_n, num_k = plan.grid(M, N, K)
     G = min(plan.num_bufs, max(1, num_m * num_n * num_k))
@@ -321,8 +349,9 @@ def gpp_matmul(
     C = ring_chunks(G, bk, sublane_tile(w.dtype.itemsize))
 
     # zero-pad ragged edges to full tiles (K-padding is correctness-neutral;
-    # M/N padding is sliced off the output).
-    Mp, Kp, Np = num_m * bm, num_k * bk, num_n * bn
+    # M/N padding is sliced off the output).  Free tiles divide K and N
+    # where the planner can, so the weight is copied only for a pinned tile
+    # or a lane dim that is no multiple of 128.
     xp = _pad2(x, Mp, Kp)
     wp = _pad2(w, Kp, Np)
 
@@ -506,20 +535,14 @@ def gpp_matmul_grouped(
 
     chip = target_chip(interpret)
     vmem_limit = vmem_budget or chip.vmem_limit_bytes
-    plan = plan_matmul_tiles(
-        M, K, N,
-        x_itemsize=x.dtype.itemsize,
-        w_itemsize=w.dtype.itemsize,
-        out_itemsize=jnp.dtype(out_dtype).itemsize,
-        block_m=block_m, block_n=block_n, block_k=block_k,
-        num_bufs=num_bufs, vmem_budget=vmem_limit, chip=chip,
-    )
+    plan, (Mp, Kp, Np) = plan_call(
+        M, K, N, x.dtype, w.dtype, block_m=block_m, block_n=block_n,
+        block_k=block_k, num_bufs=num_bufs, vmem_budget=vmem_limit, chip=chip)
     bm, bn, bk = plan.block_m, plan.block_n, plan.block_k
     num_m, num_n, num_k = plan.grid(M, N, K)
     G = min(plan.num_bufs, max(1, E * num_m * num_n * num_k))
     C = ring_chunks(G, bk, sublane_tile(w.dtype.itemsize))
 
-    Mp, Kp, Np = num_m * bm, num_k * bk, num_n * bn
     xp = _pad3(x, Mp, Kp)
     wp = _pad3(w, Kp, Np)
 

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -26,6 +27,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.streamer import StreamSettings, stream_layers
+from repro.kernels.gpp_matmul import target_chip, weight_pad_bytes
+from repro.kernels.ops import resolve_dense_mode
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
@@ -551,6 +554,60 @@ def served_moe_rows(cfg: ModelConfig, rows: int, real: int
     c = _moe_cfg(cfg, dropless=True)
     return (layers * c.experts_per_token * real,
             layers * moe_mod.expert_rows(c, rows))
+
+
+# attention's projections a served step streams through `dense`, with the
+# weight dims each contracts (MLA's w_uk and w_uv are folded into einsums
+# on the kernel read path)
+_SERVED_PROJECTIONS = {"w_q": 1, "w_k": 1, "w_v": 1, "w_dq": 1, "w_uq": 1,
+                       "w_dkv": 1, "w_o": 2}
+
+
+def _served_matmuls(cfg: ModelConfig, kind: str, rows: int):
+    """(rows, weight, x dtype) of each `dense` / `dense_grouped` call of
+    one served block of `kind` over `rows` tokens, the weight as the call
+    sees it: a (K, N) or, grouped, an (E, K, N) ShapeDtypeStruct."""
+    sp = block_specs(cfg, kind)
+    dt = cfg.jdtype
+    calls = []
+    for name, w in sp["attn"].items():
+        c = _SERVED_PROJECTIONS.get(name)
+        if c:
+            calls.append((rows, sds((math.prod(w.shape[:c]),
+                                     math.prod(w.shape[c:])), w.dtype), dt))
+    calls += [(rows, w, dt) for w in sp.get("mlp", {}).values()]
+    if "moe" in sp:
+        m, c = sp["moe"], _moe_cfg(cfg, dropless=True)
+        names = (("w_gate", "w_up", "w_down") if c.act == "swiglu"
+                 else ("w_up", "w_down"))
+        calls.append((rows, sds(m["router"].shape, c.router_dtype),
+                      c.router_dtype))
+        per_expert = moe_mod.expert_rows(c, rows) // c.num_experts
+        calls += [(per_expert, m[k], dt) for k in names]
+        calls += [(rows, m["shared"][k], dt) for k in names
+                  if k in m.get("shared", {})]
+    return calls
+
+
+def served_gpp_pad_bytes(cfg: ModelConfig, rows: int) -> int:
+    """Weight bytes one served step program over `rows` tokens copies into
+    zero-padded `gpp_matmul` / `gpp_matmul_grouped` operands, summed over
+    its layers: each call `dense` routes to a kernel, padded as the
+    wrappers pad it (`gpp_matmul.weight_pad_bytes`).  0 when every weight
+    is routed to the reference or every planned tile divides its weight."""
+    total = 0
+    kinds = ([(k, 1) for k in cfg.prefix_pattern]
+             + [(k, cfg.num_superblocks) for k in cfg.pattern])
+    for kind, count in kinds:
+        for M, w, x_dtype in _served_matmuls(cfg, kind, rows):
+            mode = resolve_dense_mode(cfg.dense_kernel, w)
+            if mode == "ref":
+                continue
+            total += count * weight_pad_bytes(
+                M, *w.shape[-2:], x_dtype, w.dtype,
+                experts=w.shape[0] if w.ndim == 3 else 1,
+                chip=target_chip(mode == "interpret"))
+    return total
 
 
 def _block_prefill_paged(cfg, kind, p, x, cache, table_row, start_pos):

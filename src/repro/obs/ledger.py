@@ -65,6 +65,10 @@ STEP_SCHEMA: "tuple[str, ...]" = (
     # experts per token x MoE layers) and those multiplied (MoE layers x
     # experts x groups x capacity); 0 for a model without MoE layers
     "moe_rows_routed", "moe_rows_computed",
+    # weight bytes the step's programs copied into zero-padded operands of
+    # gpp_matmul / gpp_matmul_grouped (counted from shapes by the paged
+    # engine, `models.transformer.served_gpp_pad_bytes`)
+    "gpp_pad_bytes",
     # wall time of the step (us; 0.0 when telemetry is disabled)
     "step_wall_us",
 )

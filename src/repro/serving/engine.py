@@ -249,6 +249,13 @@ class ServingEngine:
         self.caches = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), specs)
         self._kv_token_bytes = self._kv_bytes_per_token(specs)
+        # weight bytes each step program copies into padded kernel operands
+        self._gpp_pad_bytes = {
+            "prefill": tf.served_gpp_pad_bytes(cfg, chunk),
+            "decode": tf.served_gpp_pad_bytes(cfg, serve.slots),
+            "verify": (tf.served_gpp_pad_bytes(
+                cfg, serve.slots * (self.draft_len + 1))
+                if self.draft_len else 0)}
         self._group_token_bytes = self._kv_bytes_by_group(cfg, specs)
         self._param_bytes = cfg.active_params() * cfg.jdtype.itemsize
         # resolved paged-attention read path ("ref" gathers pools, else the
@@ -534,6 +541,7 @@ class ServingEngine:
         gathered = 0            # lanes whose logical sequence a gather reads
         live = []               # live-block counts of each call's lanes
         moe_routed = moe_computed = 0
+        gpp_pad = 0             # weight bytes padded for the kernels
         self._d2h = 0
 
         if plan.prefill:
@@ -559,6 +567,7 @@ class ServingEngine:
             prefill_tokens = len(w.tokens)
             moe_routed, moe_computed = tf.served_moe_rows(
                 self.cfg, len(w.tokens), w.real_tokens)
+            gpp_pad += self._gpp_pad_bytes["prefill"]
             read_tokens += w.start_pos + len(w.tokens)
             if w.final:
                 with phase(trace, "serve.prefill.fetch", tid=lane_tid):
@@ -604,6 +613,7 @@ class ServingEngine:
             r, c = tf.served_moe_rows(self.cfg, slots,
                                       len(plan.decode_lanes))
             moe_routed, moe_computed = moe_routed + r, moe_computed + c
+            gpp_pad += self._gpp_pad_bytes["decode"]
             with phase(trace, "serve.decode.fetch"):
                 logits_np = self._fetch(logits)
             with phase(trace, "serve.sample"):
@@ -653,6 +663,7 @@ class ServingEngine:
             r, c = tf.served_moe_rows(self.cfg, slots * S,
                                       int(nvalid.sum()))
             moe_routed, moe_computed = moe_routed + r, moe_computed + c
+            gpp_pad += self._gpp_pad_bytes["verify"]
             with phase(trace, "serve.verify.fetch"):
                 logits_np = self._fetch(logits)
             with phase(trace, "serve.sample"):
@@ -735,6 +746,7 @@ class ServingEngine:
                 d2h_bytes=self._d2h,
                 moe_rows_routed=moe_routed,
                 moe_rows_computed=moe_computed,
+                gpp_pad_bytes=gpp_pad,
                 step_wall_us=(obs.now_us() - step_t0) if obs.enabled else 0.0,
             )
             if obs.enabled:

@@ -9,14 +9,17 @@ from _hypothesis_compat import given, settings, st
 
 pytestmark = pytest.mark.tier1
 
-from repro.core.schedule import plan_matmul_tiles
+from repro.core.schedule import V5E, plan_matmul_tiles
 from repro.kernels.gpp_matmul import (
-    _chunk_bounds, chunk_issue_schedule, gpp_matmul,
+    _chunk_bounds, chunk_issue_schedule, gpp_matmul, gpp_matmul_grouped,
+    plan_call, weight_pad_bytes,
 )
 from repro.kernels.ops import (
     dense, plan_ring_depth, streamed_gemm_sequence, streamed_matmul,
 )
-from repro.kernels.ref import dense_ref, matmul_ref, streamed_gemm_seq_ref
+from repro.kernels.ref import (
+    dense_grouped_ref, dense_ref, matmul_ref, streamed_gemm_seq_ref,
+)
 
 jax.config.update("jax_enable_x64", False)
 
@@ -41,6 +44,23 @@ TILED_SHAPES = [
     (16, 640, 384, 16, 128, 256),   # ragged K tile (640 = 2.5 * 256)
     (24, 128, 300, 8, 256, 128),    # ragged N < block_n on last tile
 ]
+
+
+# (M, K, N) of each served bf16 weight at its benchmark cell's decode rows
+SERVED_WEIGHTS = {
+    "qwen2-7b q/o": (16, 3584, 3584),
+    "qwen2-7b k/v": (16, 3584, 512),
+    "qwen2-7b gate/up": (16, 3584, 18944),
+    "qwen2-7b down": (16, 18944, 3584),
+    "qwen1.5-0.5b q/k/v/o": (4, 1024, 1024),
+    "qwen1.5-0.5b gate/up": (4, 1024, 2816),
+    "qwen1.5-0.5b down": (4, 2816, 1024),
+    "deepseek-v2-lite routed gate/up": (32, 2048, 1408),
+    "deepseek-v2-lite routed down": (32, 1408, 2048),
+    "deepseek-v2-lite shared down": (32, 2816, 2048),
+    "deepseek-v2-lite dense down": (32, 10944, 2048),
+    "deepseek-v2-lite w_dkv": (32, 2048, 576),
+}
 
 
 class TestNumerics:
@@ -153,6 +173,31 @@ class TestNumerics:
         x, w = rand(k1, (M, K), jnp.float32), rand(k2, (K, N), jnp.float32)
         y = gpp_matmul(x, w, interpret=True)  # auto-planned tiles
         np.testing.assert_allclose(np.asarray(y), np.asarray(matmul_ref(x, w)),
+                                   rtol=1e-5, atol=2e-3)
+
+    @pytest.mark.parametrize("E,M,K,N", [
+        (0, 8, 11 * 128, 37 * 128),     # flat: DeepSeek x qwen2 d_ff
+        (2, 8, 37 * 128, 11 * 128),     # grouped: qwen2 d_ff x DeepSeek
+    ], ids=["gpp_matmul", "gpp_matmul_grouped"])
+    def test_divisor_tiles_match_oracle(self, E, M, K, N):
+        """Weights with the served shapes' factors (a prime number of
+        128-lane tiles) run on planned tiles that divide them, with no pad,
+        and match the oracle."""
+        k1, k2 = jax.random.split(jax.random.PRNGKey(K + N))
+        lead = (E,) if E else ()
+        x = rand(k1, (*lead, M, K), jnp.float32)
+        w = rand(k2, (*lead, K, N), jnp.float32)
+        plan, padded = plan_call(M, K, N, jnp.float32, jnp.float32)
+        assert padded == (M, K, N)
+        assert weight_pad_bytes(M, K, N, jnp.float32, jnp.float32) == 0
+        assert plan.grid(M, N, K)[1:] != (1, 1)     # more than one tile
+        if E:
+            got = gpp_matmul_grouped(x, w, interpret=True)
+            want = dense_grouped_ref(x, w)
+        else:
+            got = gpp_matmul(x, w, interpret=True)
+            want = matmul_ref(x, w)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=2e-3)
 
     def test_dense_kernel_path_is_differentiable(self):
@@ -274,6 +319,34 @@ class TestPlanner:
         deep ring; compute-bound (large M) degenerates to double buffering."""
         assert plan_ring_depth(8, 256, 256) >= 4
         assert plan_ring_depth(1024, 256, 256) == 2
+
+    @pytest.mark.parametrize("M,K,N", SERVED_WEIGHTS.values(),
+                             ids=list(SERVED_WEIGHTS))
+    def test_free_tiles_divide_served_weights(self, M, K, N):
+        """A free plan streams each served bf16 weight as it lies in HBM:
+        block_k divides K and block_n divides N (a lane dim that is no
+        128-lane multiple, MLA's latent projection of 512 + 64, pads only
+        to the next whole lane), the working set fits the budget, and a
+        weight tile moves at least 512 KiB."""
+        bf16 = jnp.bfloat16
+        plan, (_, Kp, Np) = plan_call(M, K, N, bf16, bf16)
+        assert (Kp, Np) == (K, -(-N // 128) * 128)
+        assert Kp % plan.block_k == 0 and Np % plan.block_n == 0
+        assert plan.vmem_bytes <= V5E.vmem_limit_bytes
+        assert plan.block_k * plan.block_n * 2 >= 512 * 1024
+        assert weight_pad_bytes(M, K, N, bf16, bf16) == (
+            0 if N % 128 == 0 else K * Np * 2)
+
+    def test_pinned_tile_that_does_not_divide_still_pads(self):
+        """DeepSeek-V2-Lite's routed gate/up (1408 = 11 x 128 columns) at a
+        pinned block_n of 256, the tile planned before divisor tiles: the
+        wrapper pads N to 1536 (six tiles), as pinned dims always did."""
+        bf16 = jnp.bfloat16
+        plan, (_, Kp, Np) = plan_call(32, 2048, 1408, bf16, bf16,
+                                      block_n=256)
+        assert plan.block_n == 256 and (Kp, Np) == (2048, 1536)
+        free, (_, _, Np_free) = plan_call(32, 2048, 1408, bf16, bf16)
+        assert Np_free == 1408 and 1408 % free.block_n == 0
 
 
 class TestSchedule:

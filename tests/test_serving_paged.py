@@ -380,3 +380,49 @@ class TestAttnReadMetrics:
         assert ker_streams == ref_streams
         assert ker_eng.trace_counts == {"prefill_chunk": 1, "decode": 1,
                                         "verify": 0}
+
+
+class TestGppPadBytes:
+    def test_served_weights_pad_only_where_no_lane_tile_divides(self):
+        """At published widths, the kernels' planned tiles divide every
+        weight of qwen2-7b and qwen1.5-0.5b.  DeepSeek-V2-Lite pads those
+        whose lane dim is no 128-lane multiple, to the next one: w_dkv
+        (576 -> 640 columns) in each of 5 layers, the dense layer's gate
+        and up (10944 -> 11008), and, forced onto the kernel, each MoE
+        layer's f32 router (64 -> 128).  Routed to the reference, as auto
+        routes on the CPU, nothing is padded."""
+        from repro.configs.deepseek_v2_lite_16b import CONFIG as DEEPSEEK
+        from repro.configs.qwen1_5_0_5b import CONFIG as QWEN_05B
+        from repro.configs.qwen2_7b import CONFIG as QWEN2_7B
+        for cfg, rows in ((QWEN2_7B.with_(num_layers=14), 16),
+                          (QWEN_05B, 4)):
+            assert tf.served_gpp_pad_bytes(
+                cfg.with_(dense_kernel="interpret"), rows) == 0
+        ds = DEEPSEEK.with_(num_layers=5)
+        want = (5 * 2048 * 640 * 2 + 2 * 2048 * 11008 * 2
+                + 4 * 2048 * 128 * 4)
+        for rows in (32, 1):
+            assert tf.served_gpp_pad_bytes(
+                ds.with_(dense_kernel="interpret"), rows) == want
+        assert tf.served_gpp_pad_bytes(ds, 32) == 0
+
+    def test_ledger_rows_carry_each_programs_pad_bytes(self, setups,
+                                                       monkeypatch):
+        """A step's `gpp_pad_bytes` adds the count of each program it
+        launched: the prefill chunk's (16 rows) and the decode call's (2
+        lanes)."""
+        monkeypatch.setattr(tf, "served_gpp_pad_bytes",
+                            lambda cfg, rows: 1000 * rows + 1)
+        cfg, params = setups["qwen1.5-0.5b"]
+        eng = ServingEngine(cfg, params, ServeConfig(
+            slots=2, max_len=64, block_size=8, prefill_chunk=16))
+        for p in _prompts(cfg, 2, (9, 21)):
+            eng.submit(p, max_new_tokens=3)
+        eng.run()
+        both = 0
+        for m in eng.metrics:
+            want = ((16_001 if m["prefill_tokens"] else 0)
+                    + (2_001 if m["decode_tokens"] else 0))
+            assert m["gpp_pad_bytes"] == want
+            both += bool(m["prefill_tokens"] and m["decode_tokens"])
+        assert both

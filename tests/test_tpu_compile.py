@@ -17,6 +17,7 @@ the described device's kind.
 from __future__ import annotations
 
 import collections
+import math
 import re
 
 import pytest
@@ -284,6 +285,42 @@ def _pool_relayouts(hlo: str) -> "list[str]":
                     == _MEMORY_SPACE.sub("", src))]
 
 
+_PAD = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = bf16\[([\d,]+)\]\S* pad\(")
+
+
+def _weight_pads(hlo: str) -> "list[tuple[int, ...]]":
+    """The dims of every `pad`, fused or not, whose result is a bf16 array
+    of 1 MiB or more: a weight (or a prefill chunk's activations padded
+    to its weight's rows)."""
+    found = []
+    for line in hlo.splitlines():
+        m = _PAD.match(line)
+        if m:
+            dims = tuple(int(d) for d in m.group(1).split(","))
+            if 2 * math.prod(dims) >= 2**20:
+                found.append(dims)
+    return found
+
+
+@pytest.fixture(scope="module")
+def served_steps(one_chip):
+    """Step programs of the benchmark's larger configurations, compiled
+    once for the tests below: `served_steps(model, step)`."""
+    from repro.configs.deepseek_v2_lite_16b import CONFIG as DEEPSEEK
+    from repro.configs.qwen2_7b import CONFIG as QWEN2_7B
+    models = {"qwen2-7b": QWEN2_7B.with_(num_layers=2),
+              "deepseek-v2-lite": DEEPSEEK.with_(num_layers=5)}
+    compiled = {}
+
+    def get(model, step):
+        if (model, step) not in compiled:
+            compile_step = {"decode": _compile_decode,
+                            "prefill": _compile_prefill}[step]
+            compiled[model, step] = compile_step(one_chip, models[model])
+        return compiled[model, step]
+    return get
+
+
 @pytest.mark.parametrize("step", ["decode", "prefill"])
 def test_qwen_pools_are_never_copied(qwen_steps, step):
     """qwen1.5-0.5b's (NB, BS, 16 x 64) pools: stored with the 64-lane head
@@ -294,19 +331,39 @@ def test_qwen_pools_are_never_copied(qwen_steps, step):
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_gqa_pools_keep_their_layout(one_chip, step):
+def test_gqa_pools_keep_their_layout(served_steps, step):
     """qwen2-7b's GQA pools (4 kv heads x 128): (4, 128) heads fill no
     (16, 128) bf16 tile, so XLA reshaped each pool into the kernel's
     (BS, 512) rows.  Two layers show every per-layer op."""
-    from repro.configs.qwen2_7b import CONFIG as QWEN2_7B
-    compile_step = {"decode": _compile_decode,
-                    "prefill": _compile_prefill}[step]
-    compiled = compile_step(one_chip, QWEN2_7B.with_(num_layers=2))
+    compiled = served_steps("qwen2-7b", step)
     assert not _pool_relayouts(compiled.as_text())
 
 
+# weights whose lane dim has no 128-lane divisor: Mosaic slices HBM in
+# whole lanes, so the wrapper pads them to the next one (DeepSeek-V2-Lite's
+# latent projection, 512 + 64 columns, in the dense layer and the scan
+# body, and the dense layer's gate and up, 10944 columns)
+LANE_RAGGED_PADS = {
+    "qwen2-7b": [],
+    "deepseek-v2-lite": [(2048, 640), (2048, 640), (2048, 11008),
+                         (2048, 11008)],
+}
+
+
 @pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_deepseek_v2_lite_steps_fit_one_chip(one_chip, step):
+@pytest.mark.parametrize("model", list(LANE_RAGGED_PADS))
+def test_step_programs_pad_no_weight(served_steps, model, step):
+    """The planner takes tiles that divide each weight, so a step program
+    copies no weight into a padded operand before the kernels stream it
+    (qwen2-7b's gate, up, down, q and o were padded to 2048-row tiles
+    every call, DeepSeek-V2-Lite's expert stacks to 256-column tiles),
+    but those whose lane dim no tile divides."""
+    pads = _weight_pads(served_steps(model, step).as_text())
+    assert sorted(pads) == LANE_RAGGED_PADS[model]
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_deepseek_v2_lite_steps_fit_one_chip(served_steps, step):
     """DeepSeek-V2-Lite's first five layers (the benchmark's one-chip
     stage: the dense layer, four MoE layers with all 64 experts, the
     embedding and the head) at published widths, YaRN and dropless experts
@@ -314,10 +371,7 @@ def test_deepseek_v2_lite_steps_fit_one_chip(one_chip, step):
     grouped kernel for gate, up and down, every layer the paged MLA kernel
     and gpp_matmul for q, the latent, o and the three MLP (shared-expert)
     projections: in the HLO, the dense layer's calls and one scan body's."""
-    from repro.configs.deepseek_v2_lite_16b import CONFIG as DEEPSEEK
-    compile_step = {"decode": _compile_decode,
-                    "prefill": _compile_prefill}[step]
-    compiled = compile_step(one_chip, DEEPSEEK.with_(num_layers=5))
+    compiled = served_steps("deepseek-v2-lite", step)
     _assert_fits(compiled)
     calls = collections.Counter(re.findall(
         r"%(gpp_matmul_grouped|gpp_matmul|paged_attention)[.\d]* = ",
